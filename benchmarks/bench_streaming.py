@@ -26,7 +26,10 @@ from benchmarks import common
 CHUNK_CELLS = 4096
 
 
-def _joint_space(n_perts: int, n_backlogs: int, n_mixes: int):
+def joint_space(n_perts: int, n_backlogs: int, n_mixes: int, *,
+                n_flits: int = 64, n_accesses: int = 64):
+    """The joint [perturbation x phy x backlog x read_fraction] space; the
+    rows here cut the simulator horizons to 64 to fit the CPU budget."""
     from repro.core import (
         DesignSpace, UCIE_A_32G_55U, UCIE_A_48G_45U, UCIE_S_32G,
         UCIE_S_48G_110U, axis,
@@ -39,10 +42,10 @@ def _joint_space(n_perts: int, n_backlogs: int, n_mixes: int):
                      UCIE_A_48G_45U]),
         axis("backlog", list(np.linspace(2.0, 128.0, n_backlogs))),
         axis("read_fraction", list(np.linspace(0.0, 1.0, n_mixes))),
-    ], n_flits=64, n_accesses=64)
+    ], n_flits=n_flits, n_accesses=n_accesses)
 
 
-def _equality_row(rows: list) -> None:
+def equality_row(rows: list) -> None:
     """Streamed winners == materialized winners on golden-shaped grids."""
     from repro.core import DesignSpace, StreamConfig, axis
 
@@ -84,7 +87,7 @@ def _joint_row(rows: list, name: str, n_perts: int, n_backlogs: int,
                n_mixes: int, min_cells: int) -> None:
     from repro.core import StreamConfig
 
-    space = _joint_space(n_perts, n_backlogs, n_mixes)
+    space = joint_space(n_perts, n_backlogs, n_mixes)
     t0 = time.perf_counter()
     sr = space.evaluate(metrics=("sim_bandwidth_gbs",),
                         stream=StreamConfig(chunk_cells=CHUNK_CELLS))
@@ -113,7 +116,7 @@ def _async_row(rows: list, name: str, n_perts: int, n_backlogs: int,
     and running bests must stay bit-identical at every depth."""
     from repro.core import StreamConfig, flitsim
 
-    space = _joint_space(n_perts, n_backlogs, n_mixes)
+    space = joint_space(n_perts, n_backlogs, n_mixes)
 
     def _eval(prefetch: int):
         t0 = time.perf_counter()
@@ -153,7 +156,7 @@ def _async_row(rows: list, name: str, n_perts: int, n_backlogs: int,
 
 
 def run(rows: list):
-    _equality_row(rows)
+    equality_row(rows)
     if common.SMOKE:
         # ~10^6 cells: 250 perts x 4 phys x 25 backlogs x 41 mixes
         _joint_row(rows, "streaming/joint_1e6_smoke", 250, 25, 41,

@@ -15,6 +15,7 @@ import traceback
 
 from benchmarks import common
 from benchmarks.common import emit
+from repro import compile_cache
 
 
 def main() -> None:
@@ -28,6 +29,7 @@ def main() -> None:
                          "BENCH_flitsim.json flit-simulation trend file")
     args = ap.parse_args()
     common.SMOKE = args.smoke
+    compile_cache.enable()
 
     rows = []
     from benchmarks import (

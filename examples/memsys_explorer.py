@@ -2,13 +2,13 @@
 
 Four modes:
 
-  * artifact mode (default) — takes a compiled workload cell from the
-    dry-run artifacts (or computes a fresh one for a reduced config),
-    derives its xRyW traffic mix from the HLO byte counts, and reports what
-    every UCIe-Memory approach would deliver for that workload — bandwidth,
+  * artifact mode (default) — takes compiled workload cells from the
+    dry-run artifacts named on the command line, derives each one's xRyW
+    traffic mix from the HLO byte counts, and reports what every
+    UCIe-Memory approach would deliver for that workload — bandwidth,
     power, latency — vs today's HBM.
 
-        PYTHONPATH=src python examples/memsys_explorer.py [cell.json]
+        PYTHONPATH=src python examples/memsys_explorer.py cell.json [...]
 
   * sweep mode — full design-space exploration over a dense 2-D
     (read-fraction x backlog) grid: the batched flit-simulation sweep
@@ -19,10 +19,11 @@ Four modes:
         PYTHONPATH=src python examples/memsys_explorer.py --sweep
 
   * bridge mode — the batched workload->design-space bridge: every
-    workload's HLO-derived traffic mix (from dry-run artifacts when
-    present, representative train/prefill/decode workloads otherwise)
-    is stacked as a workload_config axis on top of the dense mix grid and
-    a shoreline axis (the axes-first DesignSpace API), and the whole
+    workload's HLO-derived traffic mix (from the dry-run artifacts named
+    after ``--bridge``, representative train/prefill/decode workloads
+    when none are) is stacked as a workload_config axis on top of the
+    dense mix grid and a shoreline axis (the axes-first DesignSpace API),
+    and the whole
     [configs x catalog x mixes x shorelines] space resolves through ONE
     compiled catalog evaluation.  Each workload reports its frontier:
     best system, read-fraction crossovers, shoreline sensitivity.  The
@@ -40,7 +41,7 @@ Four modes:
     (``ADAPTIVE_SIM``) — the chunked cores early-exit once every grid
     cell's estimate converges, deviating <= ~1e-3 from the fixed engine.
 
-        PYTHONPATH=src python examples/memsys_explorer.py --bridge
+        PYTHONPATH=src python examples/memsys_explorer.py --bridge [cell ...]
 
   * serving mode — the serving-trace frontier: synthetic serving traces
     (per-model memory traffic under Poisson/diurnal/bursty arrival
@@ -53,7 +54,6 @@ Four modes:
 
         PYTHONPATH=src python examples/memsys_explorer.py --serving
 """
-import glob
 import json
 import os
 import sys
@@ -61,13 +61,14 @@ import time
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import TrafficMix, rank, SelectionConstraints
 
 DRYRUN = os.path.join(os.path.dirname(__file__), "..", "experiments",
                       "dryrun")
 
-def _cell_artifacts():
-    """Decoded per-cell artifacts as (path, dict) pairs.
+def _cell_artifacts(paths):
+    """Decoded per-cell artifacts among ``paths`` as (path, dict) pairs.
 
     The aggregate design-space report (and any axes-first export carrying
     phy / catalog_param dimensions) lives next to the per-cell artifacts
@@ -76,7 +77,7 @@ def _cell_artifacts():
     """
     from repro.roofline.analysis import is_cell_artifact
     out = []
-    for f in sorted(glob.glob(os.path.join(DRYRUN, "*.json"))):
+    for f in paths:
         try:
             with open(f) as fh:
                 d = json.load(fh)
@@ -85,11 +86,6 @@ def _cell_artifacts():
         if is_cell_artifact(d):
             out.append((f, d))
     return out
-
-
-def _cell_files():
-    """Paths of the per-cell artifacts (see :func:`_cell_artifacts`)."""
-    return [f for f, _ in _cell_artifacts()]
 
 
 def explore(d: dict):
@@ -282,27 +278,26 @@ def serving_mode():
         print(f"    {name:22s} read fraction {rf}  backlog {bl}")
 
 
-def bridge_mode(n_fracs: int = 41, shorelines=(2.0, 4.0, 8.0, 16.0)):
-    """Batched workload->design-space bridge over all available cells."""
-    from repro.core.memsys import grid_cache_stats
-    from repro.roofline.analysis import RooflineReport, bridge_design_space
+def representative_reports():
+    """:data:`REPRESENTATIVE_WORKLOADS` as roofline reports."""
+    from repro.roofline.analysis import RooflineReport
+    return {name: RooflineReport(
+        arch=name, shape="-", mesh="-", chips=256,
+        hlo_flops_per_chip=0.0, hlo_bytes_per_chip=hb,
+        collective_bytes_per_chip=0.0, compute_s=0.0,
+        memory_s=hb / 8.192e11, collective_s=0.0,
+        dominant="memory", model_flops=0.0, useful_flops_ratio=0.0,
+        read_bytes_per_chip=r, write_bytes_per_chip=w)
+        for name, (r, w, hb) in REPRESENTATIVE_WORKLOADS.items()}
 
-    reports = {}
-    for _, d in _cell_artifacts():
-        reports[f"{d['arch']}__{d['shape']}__{d['mesh']}"] = RooflineReport(
-            **d["roofline"])
-    if reports:
-        print(f"{len(reports)} workload cells from dry-run artifacts")
-    else:
-        print("no dry-run artifacts; using representative workloads")
-        for name, (r, w, hb) in REPRESENTATIVE_WORKLOADS.items():
-            reports[name] = RooflineReport(
-                arch=name, shape="-", mesh="-", chips=256,
-                hlo_flops_per_chip=0.0, hlo_bytes_per_chip=hb,
-                collective_bytes_per_chip=0.0, compute_s=0.0,
-                memory_s=hb / 8.192e11, collective_s=0.0,
-                dominant="memory", model_flops=0.0, useful_flops_ratio=0.0,
-                read_bytes_per_chip=r, write_bytes_per_chip=w)
+
+def bridge_report(reports, n_fracs: int = 41,
+                  shorelines=(2.0, 4.0, 8.0, 16.0)):
+    """The design_space.json report for ``reports``: the batched
+    workload->design-space bridge plus the joint, PHY, simulated-PHY and
+    serving frontiers.  Prints its progress."""
+    from repro.core.memsys import grid_cache_stats
+    from repro.roofline.analysis import bridge_design_space
 
     t0 = time.perf_counter()
     ds = bridge_design_space(reports, n_fracs=n_fracs,
@@ -379,11 +374,28 @@ def bridge_mode(n_fracs: int = 41, shorelines=(2.0, 4.0, 8.0, 16.0)):
     print()
     sf = serving_frontier_report()
 
-    from repro.roofline.analysis import DESIGN_SPACE_JSON
     ds["joint_frontier"] = jf
     ds["phy_frontier"] = pf
     ds["sim_phy_frontier"] = spf
     ds["serving_frontier"] = sf
+    return ds
+
+
+def bridge_mode(paths=()):
+    """Batched workload->design-space bridge over the dry-run artifacts
+    in ``paths`` (the representative workloads when there are none);
+    writes design_space.json."""
+    from repro.roofline.analysis import DESIGN_SPACE_JSON, RooflineReport
+
+    reports = {f"{d['arch']}__{d['shape']}__{d['mesh']}":
+               RooflineReport(**d["roofline"])
+               for _, d in _cell_artifacts(paths)}
+    if reports:
+        print(f"{len(reports)} workload cells from dry-run artifacts")
+    else:
+        print("no dry-run artifacts given; using representative workloads")
+        reports = representative_reports()
+    ds = bridge_report(reports)
     os.makedirs(DRYRUN, exist_ok=True)
     out_path = os.path.join(DRYRUN, DESIGN_SPACE_JSON)
     with open(out_path, "w") as f:
@@ -393,23 +405,20 @@ def bridge_mode(n_fracs: int = 41, shorelines=(2.0, 4.0, 8.0, 16.0)):
 
 def main():
     args = [a for a in sys.argv[1:]]
+    compile_cache.enable()
     if "--sweep" in args:
         sweep_mode()
         return
     if "--bridge" in args:
-        bridge_mode()
+        bridge_mode([a for a in args if a != "--bridge"])
         return
     if "--serving" in args:
         serving_mode()
         return
-    if args:
-        with open(args[0]) as fh:
-            cells = [json.load(fh)]
-    else:
-        cells = [d for _, d in _cell_artifacts()[:3]]
+    cells = [d for _, d in _cell_artifacts(args)]
     if not cells:
-        print("no dry-run artifacts; run "
-              "`PYTHONPATH=src python -m repro.launch.dryrun --all` first "
+        print("no dry-run artifacts given; pass cell JSON files written by "
+              "`PYTHONPATH=src python -m repro.launch.dryrun --all` "
               "(or try `--sweep` for the design-space sweep, which needs "
               "no artifacts)")
         return

@@ -1,11 +1,3 @@
 """Paper reproduction: on-package memory over UCIe (approaches A-E),
 roofline analysis of compiled workloads, and the workload->design-space
-bridge connecting them.
-
-Importing the package applies :mod:`repro.compat` — version-tolerant JAX
-aliases plus layout-invariant (partitionable) threefry RNG, which every
-sharded-init / elastic-checkpoint path relies on.  Keeping the flip here
-makes it unconditional: any ``import repro.<anything>`` gets it, rather
-than only the modules that happen to import a compat alias.
-"""
-from repro import compat  # noqa: F401
+bridge connecting them."""

@@ -30,7 +30,6 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.compat import tree_flatten_with_path
 
 _MANIFEST = "manifest.json"
 _COMMITTED = "_COMMITTED"
@@ -55,7 +54,7 @@ def _from_saved(arr: np.ndarray, dtype_str: str) -> np.ndarray:
 
 
 def _leaf_paths(tree) -> List[Tuple[str, Any]]:
-    flat, _ = tree_flatten_with_path(tree)
+    flat, _ = jax.tree.flatten_with_path(tree)
     out = []
     for path, leaf in flat:
         name = "/".join(str(getattr(p, "key", getattr(p, "idx", p)))

@@ -1157,7 +1157,7 @@ def _run_asymmetric_periodic(pstack, x, y, horizon: int, sim: SimConfig):
     # the row-stacking runs INSIDE the cached program: the whole periodic
     # run is one dispatch from the host's point of view
     if sim.engine == "pallas":
-        tile, cpad = fs_ops.tile_for(cells)
+        tile, cpad = fs_ops.tile_for(cells, fs_ops.ASYM_PERIODIC_MAX_TILE)
 
         def build(ps, xs, ys):
             rows = fs_ops.pad_cells(_asym_param_rows(ps, xs, ys), cpad)

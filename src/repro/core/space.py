@@ -325,10 +325,10 @@ def cached_program(family: str, key: Tuple, build_fn: Callable,
     """Return a compiled executable for ``build_fn`` memoized on
     ``(family, *key)``.
 
-    Ahead-of-time compilation (``lower().compile()``) is preferred; if the
-    backend refuses, the jitted callable (with jax's own in-memory cache)
-    is stored instead.  A second identically-keyed request is a cache hit
-    and runs the warm executable with zero retracing.  Each family keeps
+    The program is compiled ahead of time (``lower().compile()``), so a
+    program the backend's compiler refuses raises here, at its first
+    request.  A second identically-keyed request is a cache hit and runs
+    the warm executable with zero retracing.  Each family keeps
     at most :data:`MAX_PROGRAMS_PER_FAMILY` executables (FIFO eviction).
     """
     stats = _FAMILY_STATS.setdefault(family, CacheStats())
@@ -338,11 +338,7 @@ def cached_program(family: str, key: Tuple, build_fn: Callable,
         stats.hits += 1
         return entry
     stats.misses += 1
-    jitted = jax.jit(build_fn)
-    try:
-        entry = jitted.lower(*example_args).compile()
-    except Exception:          # pragma: no cover - backend-specific fallback
-        entry = jitted
+    entry = jax.jit(build_fn).lower(*example_args).compile()
     family_keys = [k for k in _PROGRAMS if k[0] == family]
     if len(family_keys) >= MAX_PROGRAMS_PER_FAMILY:
         del _PROGRAMS[family_keys[0]]        # dict order == insertion order
@@ -369,6 +365,12 @@ def cache_stats(families: Optional[Sequence[str]] = None) -> CacheStats:
             out.hits += st.hits
             out.misses += st.misses
     return out
+
+
+def programs(families: Sequence[str]) -> Dict[Tuple, Any]:
+    """The cached executables of ``families``, keyed ``(family, *key)``
+    (what ``compiled.as_text()`` shows is what the device runs)."""
+    return {k: v for k, v in _PROGRAMS.items() if k[0] in families}
 
 
 def clear_cache(families: Optional[Sequence[str]] = None) -> None:

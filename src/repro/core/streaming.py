@@ -7,9 +7,9 @@ targets.  This module is the other execution mode behind the SAME axes:
 ``evaluate(..., stream=StreamConfig(...))`` flattens the cell space along
 a configurable axis order, cuts it into per-device chunks, and pushes
 every chunk through ONE executable (shared shape-keyed compile cache,
-families ``stream.*``) that is ``shard_map``-ped across devices via the
-:mod:`repro.compat` shim.  Frontier / argbest / feasibility resolve as
-RUNNING on-device reductions:
+families ``stream.*``) that is ``shard_map``-ped across devices.
+Frontier / argbest / feasibility resolve as RUNNING on-device
+reductions:
 
 * per-cell winner codes (one small int per cell — the only per-cell
   output that ever exists),
@@ -50,6 +50,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import time
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -58,7 +59,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec
 
-from repro import compat
 from repro.core import space as space_mod
 
 __all__ = ["StreamResult", "stream_evaluate"]
@@ -68,6 +68,16 @@ STREAM_SIM_METRICS: Tuple[str, ...] = ("sim_efficiency",
                                        "sim_bandwidth_gbs")
 
 _MESHES: Dict[int, Any] = {}
+
+#: ``shard_map`` with its varying-manual-axes check off.  The chunk bodies
+#: vmap the fixed-horizon reference cores, whose ``lax.scan`` carries
+#: start as unvarying constants and become varying over ``chunks`` after
+#: one step; the check rejects that carry.  Marking the carries varying
+#: would make the shared reference cores mesh-aware for every caller, and
+#: nothing here needs the check: each body's only collectives are the
+#: explicit ``psum`` / ``pmax`` of per-device partials, and ``out_specs``
+#: already says which outputs are replicated.
+_shard_map = functools.partial(jax.shard_map, check_vma=False)
 
 
 def _mesh(devices: int):
@@ -338,7 +348,7 @@ def _stream_sim(space, metric: str, sim, stream) -> StreamResult:
             best = jax.lax.pmax(best, "chunks")
             return codes, counts, best
 
-        sharded = compat.shard_map(
+        sharded = _shard_map(
             body, mesh=mesh,
             in_specs=(spec_c, spec_c, spec_c, spec_c,
                       spec_c, spec_c, spec_c, spec_r, spec_c),
@@ -568,7 +578,7 @@ def _stream_catalog(space, metric: str, sim, stream) -> StreamResult:
                 best, "chunks")
             return codes, counts, best, none_ct
 
-        sharded = compat.shard_map(
+        sharded = _shard_map(
             body, mesh=mesh,
             in_specs=(spec_c, spec_c, spec_c, spec_c, spec_r, spec_c),
             out_specs=(spec_c, spec_r, spec_r, spec_r))

@@ -32,19 +32,16 @@ from repro.kernels.flit_sim.ref import (
     symmetric_chunk_compute, symmetric_periodic_compute,
 )
 
-#: jax renamed TPUCompilerParams -> CompilerParams; support both so the
-#: CI floor (0.4.x) and latest lower the same source
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
-
 #: cell-axis tile: one lane row minimum, a few VPU rows maximum — the
 #: state working set per tile stays well under VMEM either way
 MAX_TILE = 8192
 LANE = 128
 
-#: the symmetric periodic observer holds 8 PERIOD_WINDOW-row bands
-#: (~520 rows of f32) per tile, so its cell tile is capped lower than
-#: the chunk kernels' to keep the window ring inside VMEM
+#: the periodic observers hold PERIOD_WINDOW-row bands per tile (4 for
+#: asymmetric, 8 for symmetric), so their cell tiles are capped lower than
+#: the chunk kernels' to fit v5e's 16 MiB of scoped VMEM: asymmetric
+#: needs 21.0 MiB at 8192, symmetric 21.3 MiB at 4096
+ASYM_PERIODIC_MAX_TILE = 4096
 SYM_PERIODIC_MAX_TILE = 2048
 
 
@@ -91,7 +88,7 @@ def symmetric_chunk(params, state, hist, scal, *, chunk: int, tile: int,
         in_specs=_row_specs(tile, (SYM_ROWS, SYM_ROWS, SYM_ROWS), 1),
         out_specs=pl.BlockSpec((SYM_ROWS, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((SYM_ROWS, c), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(params, state, hist, scal)
@@ -113,7 +110,7 @@ def asymmetric_periodic(params, *, n_accesses: int, tile: int,
         in_specs=_row_specs(tile, (ASYM_ROWS,), 0),
         out_specs=pl.BlockSpec((ASYM_ROWS, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((ASYM_ROWS, c), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(params)
@@ -136,7 +133,7 @@ def symmetric_periodic(params, *, n_flits: int, tile: int,
         in_specs=_row_specs(tile, (SYM_ROWS,), 0),
         out_specs=pl.BlockSpec((SYM_PERIODIC_ROWS, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((SYM_PERIODIC_ROWS, c), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(params)
@@ -159,7 +156,7 @@ def pipelining_chunk(params, state, hist, scal, *, chunk: int, tile: int,
         in_specs=_row_specs(tile, (PIPE_ROWS, PIPE_ROWS, ASYM_ROWS), 1),
         out_specs=pl.BlockSpec((PIPE_ROWS, tile), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((PIPE_ROWS, c), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(params, state, hist, scal)

@@ -25,6 +25,7 @@ from repro.kernels.flit_sim.ref import (  # noqa: F401  (re-exported)
 
 pad_cells = _k.pad_cells
 tile_for = _k.tile_for
+ASYM_PERIODIC_MAX_TILE = _k.ASYM_PERIODIC_MAX_TILE
 SYM_PERIODIC_MAX_TILE = _k.SYM_PERIODIC_MAX_TILE
 
 

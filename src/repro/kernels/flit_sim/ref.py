@@ -103,6 +103,51 @@ PIPE_MAX_K = 8
 DRIFT_SPAN = 3.0
 
 
+# -- periodic observation helpers --------------------------------------------
+# Written with what Mosaic lowers: one-hot masked window writes instead of
+# dynamic_update_slice, and a min over a lag iota instead of reversed rows,
+# cumsum or an argmax over the sublane axis.
+
+
+def _observe(step, core, cells: int):
+    """Run ``step`` PERIOD_WINDOW times from ``core``; returns the final
+    core and one ``[PERIOD_WINDOW, cells]`` band per core component, row
+    ``i`` holding that component after observed step ``i``."""
+    W = PERIOD_WINDOW
+    rows = jax.lax.broadcasted_iota(jnp.int32, (W, cells), 0)
+
+    def obs(i, carry):
+        core, bands = carry
+        core = step(core)
+        hit = rows == i
+        return core, tuple(jnp.where(hit, v[None, :], b)
+                           for v, b in zip(core, bands))
+
+    bands0 = tuple(jnp.zeros((W, cells), jnp.float32) for _ in core)
+    return jax.lax.fori_loop(0, W, obs, (core, bands0))
+
+
+def _lagged(band):
+    """The PERIOD_MAX rows before the last one; row ``j`` lags the last
+    row by ``d = PERIOD_MAX - j`` steps (see :func:`_lag_rows`)."""
+    return band[PERIOD_WINDOW - 1 - PERIOD_MAX:PERIOD_WINDOW - 1]
+
+
+def _lag_rows(cells: int):
+    """``[PERIOD_MAX, cells]`` f32 lag ``d`` of each :func:`_lagged` row."""
+    return (PERIOD_MAX - jax.lax.broadcasted_iota(
+        jnp.int32, (PERIOD_MAX, cells), 0)).astype(jnp.float32)
+
+
+def _smallest_lag(ok):
+    """(smallest lag ``d`` whose row of ``ok`` holds — 1 where none does —
+    as int32, detected flag) per cell.  Lags are small exact f32 ints."""
+    lag = jnp.min(jnp.where(ok, _lag_rows(ok.shape[1]), float(PERIOD_WINDOW)),
+                  axis=0)
+    detected = lag <= PERIOD_MAX
+    return jnp.where(detected, lag, 1.0).astype(jnp.int32), detected
+
+
 def symmetric_chunk_compute(params, state, hist, scal, *, chunk: int):
     """Advance every cell ``chunk`` cycles and re-evaluate report + drift
     + convergence — the whole per-chunk body of the adaptive symmetric
@@ -184,27 +229,14 @@ def asymmetric_periodic_compute(params, *, n_accesses: int):
     core = tuple(jnp.zeros((cells,), jnp.float32) for _ in range(4))
     core = jax.lax.fori_loop(0, PERIOD_WARM, lambda _, c: step(c), core)
 
-    # observation window: 4 stacked W-row bands (t_read / t_write /
-    # t_cmd / credit boundaries after each observed step)
-    def obs(i, carry):
-        core, win = carry
-        core = step(core)
-        for band, v in enumerate(core):
-            win = jax.lax.dynamic_update_slice(
-                win, v[None, :], (band * W + i, 0))
-        return core, win
-
-    win0 = jnp.zeros((4 * W, cells), jnp.float32)
-    core, win = jax.lax.fori_loop(0, W, obs, (core, win0))
-    tr, tw, tc, cr = (win[0:W], win[W:2 * W], win[2 * W:3 * W],
-                      win[3 * W:4 * W])
+    # observation window: 4 W-row bands (t_read / t_write / t_cmd /
+    # credit boundaries after each observed step)
+    _, (tr, tw, tc, cr) = _observe(step, core, cells)
 
     # smallest lag d with matching credit phase; the credit alone
     # determines all future increments, so a phase match is a period
-    lag = cr[W - 1 - PERIOD_MAX:W - 1][::-1]          # row j <-> d = j+1
-    ok = jnp.abs(cr[W - 1][None, :] - lag) < PERIOD_EPS
-    detected = jnp.any(ok, axis=0)
-    d = jnp.argmax(ok, axis=0).astype(jnp.int32) + 1
+    ok = jnp.abs(cr[W - 1][None, :] - _lagged(cr)) < PERIOD_EPS
+    d, detected = _smallest_lag(ok)
 
     rem = n_accesses - PERIOD_OBS
     m = rem // d
@@ -262,41 +294,33 @@ def symmetric_periodic_compute(params, *, n_flits: int):
     core = jax.lax.fori_loop(0, PERIOD_WARM,
                              lambda _, c: step(c)[0], core)
 
-    # observation window: 8 stacked W-row bands — the 7 core components
-    # after each observed cycle plus that cycle's data-slot delivery
-    def obs(i, carry):
-        core, win = carry
-        core, nd = step(core)
-        for band, v in enumerate(core + (nd,)):
-            win = jax.lax.dynamic_update_slice(
-                win, v[None, :], (band * W + i, 0))
-        return core, win
+    # observation window: 8 W-row bands — the 7 core components after
+    # each observed cycle plus that cycle's data-slot delivery
+    def step_nd(c):
+        c, nd = step(c[:7])
+        return c + (nd,)
 
-    win0 = jnp.zeros((8 * W, cells), jnp.float32)
-    core, win = jax.lax.fori_loop(0, W, obs, (core, win0))
-    dwin = win[7 * W:8 * W]
+    _, bands = _observe(step_nd, core + (jnp.zeros_like(core[0]),), cells)
+    dwin = bands[7]
 
     # smallest lag d whose full core matches EXACTLY; the core alone
     # determines the whole future trajectory, so an exact match repeats
     # the delivery window verbatim forever
     ok = None
-    for c in range(7):
-        band = win[c * W:(c + 1) * W]
-        lag = band[W - 1 - PERIOD_MAX:W - 1][::-1]    # row j <-> d = j+1
-        eq = band[W - 1][None, :] == lag
+    for band in bands[:7]:
+        eq = band[W - 1][None, :] == _lagged(band)
         ok = eq if ok is None else ok & eq
     # integer-delivery gate: all f32 partial sums of an integer window
     # below 2^24 are exact, so the closed form equals the fixed engine's
-    # sequential fold bit-for-bit
-    is_int = (jnp.floor(dwin) == dwin).astype(jnp.float32)
-    suffix = jnp.cumsum(is_int[::-1], axis=0)         # rows from the end
-    need = jax.lax.broadcasted_iota(
-        jnp.float32, (PERIOD_MAX, cells), 0) + 1.0
-    ok = ok & (suffix[:PERIOD_MAX] == need)
-    detected = jnp.any(ok, axis=0)
-    d = jnp.argmax(ok, axis=0).astype(jnp.int32) + 1
-
+    # sequential fold bit-for-bit.  Lag d passes when the last d
+    # deliveries are integers, i.e. d is below the distance from the end
+    # of the window to its last fractional delivery (W when none is).
     rows = jax.lax.broadcasted_iota(jnp.int32, (W, cells), 0)
+    frac_at = jnp.min(jnp.where(jnp.floor(dwin) == dwin, float(W),
+                                (W - rows).astype(jnp.float32)), axis=0)
+    ok = ok & (_lag_rows(cells) < frac_at[None, :])
+    d, detected = _smallest_lag(ok)
+
     in_period = rows >= (W - d)[None, :]              # last d deliveries
     psum = jnp.sum(jnp.where(in_period, dwin, 0.0), axis=0)
 
@@ -332,7 +356,7 @@ def pipelining_chunk_compute(params, state, hist, scal, *, chunk: int):
     link_free, idx = state[PIPE_MAX_K], state[PIPE_MAX_K + 1]
     rep_prev = state[PIPE_MAX_K + 2]
     rows = jax.lax.broadcasted_iota(
-        jnp.float32, (PIPE_MAX_K, dev_ready.shape[1]), 0)
+        jnp.int32, (PIPE_MAX_K, dev_ready.shape[1]), 0).astype(jnp.float32)
 
     def body(_, carry):
         dev_ready, link_free, idx = carry

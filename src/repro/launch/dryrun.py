@@ -1,9 +1,14 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"),
+    "--xla_force_host_platform_device_count=512")))
 
-# ^ MUST precede every other import: jax locks the device count on first
-# initialization.  This module is the ONLY place the 512 placeholder
-# devices exist — smoke tests and benchmarks see the real single device.
+# ^ MUST precede every other import: jax locks the platform and the device
+# count on first initialization.  The AOT pod analysis runs on 512
+# placeholder CPU devices and never takes an accelerator; this module is
+# the ONLY place they exist — smoke tests and benchmarks see the real
+# devices.
 
 import argparse            # noqa: E402
 import dataclasses         # noqa: E402

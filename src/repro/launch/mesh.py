@@ -13,14 +13,22 @@ Topology contract (DESIGN.md §4):
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``.  jax defaults to
+    ``Explicit`` axes, under which the models' embedding gathers need an
+    ``out_sharding``; the sharding rules here are written for ``Auto``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
     """Small mesh for CPU multi-device tests (subprocess sets device count)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

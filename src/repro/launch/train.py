@@ -36,6 +36,7 @@ def main():
 
     from repro.configs import get
     from repro.configs.shapes import ShapeSpec
+    from repro.launch.mesh import make_mesh
     from repro.models import ShardingCtx, build, from_mesh
     from repro.runtime import DriverConfig, StragglerMonitor, run
     from repro.train import (
@@ -51,7 +52,7 @@ def main():
 
     if args.mesh:
         d, m = (int(v) for v in args.mesh.split(","))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
         ctx = from_mesh(mesh)
     else:
         ctx = ShardingCtx()

@@ -10,7 +10,6 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.compat import tree_flatten_with_path, tree_map_with_path
 from repro.configs.base import ModelConfig
 from repro.configs.shapes import ShapeSpec
 from repro.models import encdec as encdec_mod
@@ -110,7 +109,7 @@ class Model:
             return jax.tree.map(_p, kv)
 
         if cfg.is_encdec:
-            return tree_map_with_path(
+            return jax.tree.map_with_path(
                 lambda path, t: (pad_kv(t, 2)
                                  if any(getattr(p, "key", None) == "self"
                                         for p in path) else t),
@@ -207,7 +206,7 @@ class Model:
                 axes = ["batch", None, "embed_act"]
             return ctx.sharding(tuple(axes), leaf.shape)
 
-        flat, treedef = tree_flatten_with_path(specs)
+        flat, treedef = jax.tree.flatten_with_path(specs)
         out = []
         for path, leaf in flat:
             names = tuple(getattr(p, "key", getattr(p, "idx", None))

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.layers import cast
 from repro.models.schema import Leaf
@@ -161,7 +160,7 @@ def moe_block(params, x, cfg: ModelConfig, ctx: ShardingCtx):
     if gated:
         in_specs.append(P(tp, fsdp, None))
         args.append(wg)
-    fn = shard_map(
+    fn = jax.shard_map(
         _sharded, mesh=mesh, in_specs=tuple(in_specs),
         out_specs=(x_spec, P()), check_vma=False)
     out, aux = fn(*args)

@@ -20,6 +20,7 @@ def run_sub(body: str, devices: int = 8, timeout: int = 900) -> str:
         import jax
         import jax.numpy as jnp
         import numpy as np
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
@@ -31,10 +32,10 @@ def run_sub(body: str, devices: int = 8, timeout: int = 900) -> str:
 
 class TestShardedTraining:
     def test_sharded_train_step_matches_single_device(self):
-        # Requires layout-invariant RNG (jax_threefry_partitionable, enabled
-        # by repro.compat): with legacy threefry, init under sharded
-        # out_shardings draws different embedding values than single-device
-        # init from the same key (0.09 max abs diff BEFORE any train step).
+        # Requires layout-invariant RNG (jax_threefry_partitionable, jax's
+        # default): with legacy threefry, init under sharded out_shardings
+        # draws different embedding values than single-device init from
+        # the same key (0.09 max abs diff BEFORE any train step).
         run_sub("""
         from repro.configs import get
         from repro.configs.shapes import ShapeSpec
@@ -55,7 +56,7 @@ class TestShardedTraining:
         s_ref, m_ref = step0(state0, src.place(src.batch_for_step(0), ctx0))
 
         # sharded (4 data x 2 model)
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         ctx = from_mesh(mesh)
         st_sh = state_shardings(model, ctx)
         state1 = jax.jit(lambda k: init_state(model, k, opt),
@@ -95,7 +96,7 @@ class TestShardedTraining:
         ctx0 = ShardingCtx()
         out0, aux0 = moe_block(params, x, cfg, ctx0)
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         ctx = from_mesh(mesh)
         out1, aux1 = jax.jit(
             lambda p, xx: moe_block(p, xx, cfg, ctx))(params, x)
@@ -120,7 +121,7 @@ class TestShardedTraining:
         model = build(cfg)
         opt = AdamW(learning_rate=constant_schedule(1e-3))
 
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         ctx_a = from_mesh(mesh_a)
         st_sh_a = state_shardings(model, ctx_a)
         state = jax.jit(lambda k: init_state(model, k, opt),
@@ -129,7 +130,7 @@ class TestShardedTraining:
         with tempfile.TemporaryDirectory() as d:
             ckpt.save(state, 0, d)
             # restore onto a DIFFERENT mesh (2x2, elastic shrink)
-            mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+            mesh_b = make_mesh((2, 2), ("data", "model"))
             ctx_b = from_mesh(mesh_b)
             st_sh_b = state_shardings(model, ctx_b)
             restored, step = ckpt.restore(
@@ -145,9 +146,9 @@ class TestShardedTraining:
     def test_compressed_psum_int8(self):
         run_sub("""
         from jax.sharding import PartitionSpec as P
-        from repro.compat import shard_map
+        from jax import shard_map
         from repro.train.grad_compress import compressed_psum
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
         def f(xb):

@@ -458,13 +458,13 @@ class TestArtifactConsumersSkipNewDims:
         # and the table renders from the surviving cell without crashing
         assert "| a | s |" in mod.dryrun_table(cells, "16x16")
 
-    def test_explorer_cell_files_skip(self, tmp_path, monkeypatch):
+    def test_explorer_cell_files_skip(self, tmp_path):
         mod = _load_module(
             os.path.join(REPO, "examples", "memsys_explorer.py"),
             "memsys_explorer")
         self._write_artifacts(str(tmp_path))
-        monkeypatch.setattr(mod, "DRYRUN", str(tmp_path))
-        files = mod._cell_files()
+        paths = sorted(str(p) for p in tmp_path.iterdir())
+        files = [f for f, _ in mod._cell_artifacts(paths)]
         assert [os.path.basename(f) for f in files] == ["cell.json"]
 
 

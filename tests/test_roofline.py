@@ -22,6 +22,7 @@ def _lower_hlo(body: str, devices: int = 8) -> str:
             "--xla_force_host_platform_device_count={devices}"
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
+        from repro.launch.mesh import make_mesh
     """) + textwrap.dedent(body)
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(ROOT, "src")
@@ -40,7 +41,7 @@ class TestHloCostModel:
                 return jnp.tanh(c @ w), None
             y, _ = jax.lax.scan(body, x, None, length=7)
             return y
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         xs = jax.ShapeDtypeStruct((64, 256), jnp.float32)
         ws = jax.ShapeDtypeStruct((256, 256), jnp.float32)
         lowered = jax.jit(f, in_shardings=(
