@@ -127,9 +127,7 @@ def run(rows: list):
             continue
         rows.append((f"flitsim/pallas_{fam.split('.')[1]}", 0.0,
                      f"engine={v['engine']};launches={v['launches']};"
-                     f"cycles_run={v['cycles_run']};"
-                     f"cycles_per_sec_per_cell="
-                     f"{v.get('cycles_per_sec_per_cell', 0.0):.0f}"))
+                     f"cycles_run={v['cycles_run']}"))
 
     # -- period-exact asymmetric cut: dense perturbation grid ---------------
     # [31 lane-count scales x 2 asym protocols x 41 mixes]; every mix has a

@@ -96,8 +96,8 @@ winning protocol per (model, QPS) point to its catalog approach — the
 
 ``flitsim.last_run_info()`` reports per-family telemetry for the last
 adaptive run: ``engine``, ``launches``, ``cycles_run``, ``elapsed_s``,
-``cycles_per_sec_per_cell``, and the detected-period histogram when the
-asymmetric periodic detector closed the run.  Trace-scan runs report
+and the detected-period histogram when a periodic probe closed the
+run.  Trace-scan runs report
 under ``<family>.trace`` with ``phases``, ``cycles_per_phase``, and
 ``state_carry_depth`` instead.
 

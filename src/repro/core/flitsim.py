@@ -89,6 +89,7 @@ from typing import Any, Dict, Mapping, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import space as space_mod
 from repro.core.space import (
@@ -925,10 +926,8 @@ def last_run_info() -> Dict[str, Dict[str, Any]]:
     Engine telemetry (PR 6): ``engine`` (``"xla"`` / ``"pallas"``),
     ``launches`` (device programs the runner dispatched — the pallas host
     loop issues one per chunk plus one per escalation pass; the XLA
-    ``while_loop`` cores are a single launch), ``elapsed_s`` (runner wall
-    time, device work blocked to completion), and
-    ``cycles_per_sec_per_cell`` (executed main-loop cycles per second per
-    grid cell — the throughput number the BENCH million-cell row reports).
+    ``while_loop`` cores are a single launch) and ``elapsed_s`` (runner
+    wall time, device work blocked to completion).
     The asymmetric periodic detector additionally reports a ``periods``
     histogram ({detected credit period: cell count}).
 
@@ -969,10 +968,9 @@ def last_run_info() -> Dict[str, Dict[str, Any]]:
         d["converged_cycles"] = {
             ("horizon" if v < 0 else str(int(v) * chunk)): int(c)
             for v, c in zip(vals, counts)}
-        if d.get("elapsed_s"):
-            d["cycles_per_sec_per_cell"] = d["cycles_run"] / d["elapsed_s"]
-        if info.get("_periods") is not None:
-            p = np.asarray(info["_periods"]).reshape(-1)
+        if info.get("_probe_out") is not None:
+            # row 2 of the probe's output holds each cell's period
+            p = np.asarray(info["_probe_out"])[2, :d["cells"]]
             pv, pc = np.unique(p[p > 0], return_counts=True)
             d["periods"] = {int(v): int(c) for v, c in zip(pv, pc)}
         info["_materialized"] = d
@@ -983,12 +981,12 @@ def last_run_info() -> Dict[str, Dict[str, Any]]:
 def _record_adaptive(family: str, horizon: int, chunk: int, k_exit,
                      conv_at, stragglers: int, *, engine: str = "xla",
                      launches: int = 1, elapsed_s: Optional[float] = None,
-                     periods=None) -> None:
+                     probe_out=None) -> None:
     _LAST_RUN_INFO[family] = {
         "mode": "adaptive", "horizon": int(horizon), "chunk": int(chunk),
         "stragglers": int(stragglers), "engine": engine,
         "launches": int(launches), "elapsed_s": elapsed_s,
-        "_k_exit": k_exit, "_conv_at": conv_at, "_periods": periods,
+        "_k_exit": k_exit, "_conv_at": conv_at, "_probe_out": probe_out,
     }
 
 
@@ -1034,14 +1032,17 @@ def _escalate_stragglers(family: str, cells_grid_fn, horizon: int, rep,
     back over the adaptive reports.  ``args_builder(idx)`` maps the padded
     ``[S, ndim]`` straggler indices to the flat-cell program's arguments.
     """
-    idx = _pad_pow2(np.argwhere(~conv_np))
-    args = args_builder(idx)
-    cells_fn = cached_program(family, ("cells", idx.shape[0], horizon),
-                              cells_grid_fn, args)
-    exact = np.asarray(cells_fn(*args))
-    rep_np = np.asarray(rep).copy()
-    rep_np[~conv_np] = exact[:int((~conv_np).sum())]
-    return jnp.asarray(rep_np)
+    with TraceAnnotation("repro.engine.escalate", family=family):
+        idx = _pad_pow2(np.argwhere(~conv_np))
+        args = args_builder(idx)
+        cells_fn = cached_program(family, ("cells", idx.shape[0], horizon),
+                                  cells_grid_fn, args, path="cells")
+        exact = cells_fn(*args)
+        with TraceAnnotation("repro.engine.readback", family=family):
+            exact = np.asarray(exact)
+            rep_np = np.asarray(rep).copy()
+        rep_np[~conv_np] = exact[:int((~conv_np).sum())]
+        return jnp.asarray(rep_np)
 
 
 def _escalation_budget(cells: int, chunk: int, horizon: int) -> int:
@@ -1169,9 +1170,11 @@ def _run_asymmetric_periodic(pstack, x, y, horizon: int, sim: SimConfig):
                 _asym_param_rows(ps, xs, ys), n_accesses=horizon)
     fn = cached_program("flitsim.asymmetric",
                         (P, M, horizon, "periodic") + sim.key(),
-                        build, (pstack, x, y))
+                        build, (pstack, x, y), path="probe")
     out = fn(pstack, x, y)
-    det_np = np.asarray(out[1, :cells]) > 0.5
+    with TraceAnnotation("repro.engine.readback",
+                         family="flitsim.asymmetric"):
+        det_np = np.asarray(out[1, :cells]) > 0.5
     undet = int((~det_np).sum())
     if undet > max(cells // 4, 8):
         return None
@@ -1192,7 +1195,7 @@ def _run_asymmetric_periodic(pstack, x, y, horizon: int, sim: SimConfig):
     _record_adaptive("flitsim.asymmetric", horizon, fs_ref.PERIOD_OBS, 1,
                      conv_at, undet, engine=sim.engine, launches=launches,
                      elapsed_s=time.perf_counter() - t0,
-                     periods=out[2, :cells])
+                     probe_out=out)
     return rep
 
 
@@ -1225,9 +1228,11 @@ def _run_symmetric_periodic(pstack, x, y, backlogs, horizon: int,
                 _sym_param_rows(ps, xs, ys, bs), n_flits=horizon)
     fn = cached_program("flitsim.symmetric",
                         (P, B, M, horizon, "periodic") + sim.key(),
-                        build, (pstack, x, y, backlogs))
+                        build, (pstack, x, y, backlogs), path="probe")
     out = fn(pstack, x, y, backlogs)
-    det_np = np.asarray(out[1, :cells]) > 0.5
+    with TraceAnnotation("repro.engine.readback",
+                         family="flitsim.symmetric"):
+        det_np = np.asarray(out[1, :cells]) > 0.5
     undet = int((~det_np).sum())
     if undet > max(cells // 4, 8):
         return None
@@ -1250,7 +1255,7 @@ def _run_symmetric_periodic(pstack, x, y, backlogs, horizon: int,
                      1, conv_at, undet, engine=sim.engine,
                      launches=launches,
                      elapsed_s=time.perf_counter() - t0,
-                     periods=out[2, :cells])
+                     probe_out=out)
     return rep
 
 
@@ -1298,7 +1303,7 @@ def _run_symmetric_pallas(pstack, x, y, backlogs, horizon: int,
         (P, B, M, horizon, "pallas-chunk") + sim.key(),
         functools.partial(fs_ops.symmetric_chunk_launch, chunk=chunk,
                           tile=tile, cells=cells),
-        (params, state, hist1, scal_for(1, m1, mid1)))
+        (params, state, hist1, scal_for(1, m1, mid1)), path="pallas_chunk")
     conv_at = np.full(cells, -1, np.int32)
     conv_np = np.zeros(cells, bool)
     k = 0
@@ -1309,7 +1314,9 @@ def _run_symmetric_pallas(pstack, x, y, backlogs, horizon: int,
         Dh.append(state[7:8])
         TDh.append(state[8:9])
         Ph.append(state[0:5])
-        conv_np = np.asarray(conv)
+        with TraceAnnotation("repro.engine.readback",
+                             family="flitsim.symmetric"):
+            conv_np = np.asarray(conv)
         conv_at[(conv_at < 0) & conv_np] = k
         if int((~conv_np).sum()) <= budget:
             break
@@ -1362,7 +1369,7 @@ def _run_pipelining_pallas(ks, ucie_line_uis, device_line_uis,
         (Kk, U, Dn, horizon, "pallas-chunk") + sim.key(),
         functools.partial(fs_ops.pipelining_chunk_launch, chunk=chunk,
                           tile=tile, cells=cells),
-        (params, state, hist, scal_for(1)))
+        (params, state, hist, scal_for(1)), path="pallas_chunk")
     conv_at = np.full(cells, -1, np.int32)
     k = 0
     while k < K:
@@ -1371,7 +1378,9 @@ def _run_pipelining_pallas(ks, ucie_line_uis, device_line_uis,
         if k == 1:      # T1 anchor for the linear-growth extrapolation
             hist = jnp.concatenate(
                 [state[8:9], jnp.zeros((7, cpad), jnp.float32)])
-        conv_np = np.asarray(conv)
+        with TraceAnnotation("repro.engine.readback",
+                             family="flitsim.pipelining"):
+            conv_np = np.asarray(conv)
         conv_at[(conv_at < 0) & conv_np] = k
         if int((~conv_np).sum()) == 0:
             break
@@ -1391,7 +1400,7 @@ def _run_symmetric(pstack, x, y, backlogs, n_flits: int,
         fn = cached_program(
             "flitsim.symmetric", (P, B, M, n_flits) + sim.key(),
             functools.partial(_symmetric_grid, n_flits=n_flits),
-            (pstack, x, y, backlogs))
+            (pstack, x, y, backlogs), path="fixed")
         return fn(pstack, x, y, backlogs)
     horizon = sim.horizon(n_flits)
     chunk = _divisor_chunk(horizon, sim.chunk)
@@ -1409,13 +1418,25 @@ def _run_symmetric(pstack, x, y, backlogs, n_flits: int,
         # through to the chunked core on mostly aperiodic grids (None).
         # Saturated grids skip the probe outright (see the
         # SYM_PERIODIC_MAX_BACKLOG note in kernels/flit_sim/ref.py)
-        rep = _run_symmetric_periodic(pstack, x, y, backlogs, horizon,
-                                      sim)
+        with TraceAnnotation("repro.engine.probe",
+                             family="flitsim.symmetric"):
+            rep = _run_symmetric_periodic(pstack, x, y, backlogs, horizon,
+                                          sim)
         if rep is not None:
             return rep
-    if sim.engine == "pallas":
-        return _run_symmetric_pallas(pstack, x, y, backlogs, horizon,
-                                     chunk, sim)
+    with TraceAnnotation("repro.engine.core", family="flitsim.symmetric"):
+        if sim.engine == "pallas":
+            return _run_symmetric_pallas(pstack, x, y, backlogs, horizon,
+                                         chunk, sim)
+        return _run_symmetric_core(pstack, x, y, backlogs, horizon, chunk,
+                                   sim)
+
+
+def _run_symmetric_core(pstack, x, y, backlogs, horizon: int, chunk: int,
+                        sim: SimConfig):
+    """The chunked adaptive XLA core: one launch, one flag readback, and
+    the exact escalation of the stragglers it strands."""
+    P, B, M = pstack.g_slots.shape[0], backlogs.shape[0], x.shape[0]
     t0 = time.perf_counter()
     budget = _escalation_budget(P * B * M, chunk, horizon)
     fn = cached_program(
@@ -1423,11 +1444,13 @@ def _run_symmetric(pstack, x, y, backlogs, n_flits: int,
         functools.partial(_symmetric_grid_adaptive, n_flits=horizon,
                           chunk=chunk, unroll=int(sim.unroll),
                           tol=float(sim.tol), budget=budget),
-        (pstack, x, y, backlogs))
+        (pstack, x, y, backlogs), path="core")
     rep, conv, k_exit, conv_at = fn(pstack, x, y, backlogs)
     stragglers = 0
     if budget > 0:                      # budget 0 can only exit converged
-        conv_np = np.asarray(conv)
+        with TraceAnnotation("repro.engine.readback",
+                             family="flitsim.symmetric"):
+            conv_np = np.asarray(conv)
         stragglers = int((~conv_np).sum())
         if stragglers:
             rep = _escalate_stragglers(
@@ -1454,7 +1477,7 @@ def _run_asymmetric(pstack, x, y, n_accesses: int,
         fn = cached_program(
             "flitsim.asymmetric", (P, M, n_accesses) + sim.key(),
             functools.partial(_asymmetric_grid, n_accesses=n_accesses),
-            (pstack, x, y))
+            (pstack, x, y), path="fixed")
         return fn(pstack, x, y)
     horizon = sim.horizon(n_accesses)
     chunk = _divisor_chunk(horizon, sim.chunk)
@@ -1465,9 +1488,20 @@ def _run_asymmetric(pstack, x, y, n_accesses: int,
         # period-exact cut (both engines): observe ~2 credit periods and
         # extrapolate; falls through to the chunked core on mostly
         # aperiodic grids (None)
-        rep = _run_asymmetric_periodic(pstack, x, y, horizon, sim)
+        with TraceAnnotation("repro.engine.probe",
+                             family="flitsim.asymmetric"):
+            rep = _run_asymmetric_periodic(pstack, x, y, horizon, sim)
         if rep is not None:
             return rep
+    with TraceAnnotation("repro.engine.core", family="flitsim.asymmetric"):
+        return _run_asymmetric_core(pstack, x, y, horizon, chunk, sim)
+
+
+def _run_asymmetric_core(pstack, x, y, horizon: int, chunk: int,
+                         sim: SimConfig):
+    """The chunked adaptive XLA core of the asymmetric family (see
+    :func:`_run_symmetric_core`)."""
+    P, M = pstack.total_lanes.shape[0], x.shape[0]
     t0 = time.perf_counter()
     budget = _escalation_budget(P * M, chunk, horizon)
     fn = cached_program(
@@ -1475,11 +1509,13 @@ def _run_asymmetric(pstack, x, y, n_accesses: int,
         functools.partial(_asymmetric_grid_adaptive, n_accesses=horizon,
                           chunk=chunk, unroll=int(sim.unroll),
                           tol=float(sim.tol), budget=budget),
-        (pstack, x, y))
+        (pstack, x, y), path="core")
     rep, conv, k_exit, conv_at = fn(pstack, x, y)
     stragglers = 0
     if budget > 0:
-        conv_np = np.asarray(conv)
+        with TraceAnnotation("repro.engine.readback",
+                             family="flitsim.asymmetric"):
+            conv_np = np.asarray(conv)
         stragglers = int((~conv_np).sum())
         if stragglers:
             rep = _escalate_stragglers(
@@ -1507,28 +1543,29 @@ def _run_pipelining(ks, ucie_line_uis, device_line_uis, max_k: int,
             "flitsim.pipelining", shape + (max_k, n_lines) + sim.key(),
             functools.partial(_pipelining_grid, max_k=max_k,
                               n_lines=n_lines),
-            (ks, ucie_line_uis, device_line_uis))
+            (ks, ucie_line_uis, device_line_uis), path="fixed")
         return fn(ks, ucie_line_uis, device_line_uis)
     horizon = sim.horizon(n_lines)
     chunk = _divisor_chunk(horizon, sim.chunk)
     if chunk < 8:
         return _run_pipelining(ks, ucie_line_uis, device_line_uis, max_k,
                                horizon, sim=FIXED_SIM)
-    if sim.engine == "pallas":
-        from repro.kernels.flit_sim.ref import PIPE_MAX_K
-        if max_k <= PIPE_MAX_K:     # kernel holds PIPE_MAX_K device rows
-            return _run_pipelining_pallas(ks, ucie_line_uis,
-                                          device_line_uis, horizon, chunk,
-                                          sim)
-    t0 = time.perf_counter()
-    fn = cached_program(
-        "flitsim.pipelining", shape + (max_k, horizon) + sim.key(),
-        functools.partial(_pipelining_grid_adaptive, max_k=max_k,
-                          n_lines=horizon, chunk=chunk,
-                          unroll=int(sim.unroll), tol=float(sim.tol)),
-        (ks, ucie_line_uis, device_line_uis))
-    rep, conv, k_exit, conv_at = fn(ks, ucie_line_uis, device_line_uis)
-    jax.block_until_ready(rep)
+    with TraceAnnotation("repro.engine.core", family="flitsim.pipelining"):
+        if sim.engine == "pallas":
+            from repro.kernels.flit_sim.ref import PIPE_MAX_K
+            if max_k <= PIPE_MAX_K:     # kernel holds PIPE_MAX_K device rows
+                return _run_pipelining_pallas(ks, ucie_line_uis,
+                                              device_line_uis, horizon,
+                                              chunk, sim)
+        t0 = time.perf_counter()
+        fn = cached_program(
+            "flitsim.pipelining", shape + (max_k, horizon) + sim.key(),
+            functools.partial(_pipelining_grid_adaptive, max_k=max_k,
+                              n_lines=horizon, chunk=chunk,
+                              unroll=int(sim.unroll), tol=float(sim.tol)),
+            (ks, ucie_line_uis, device_line_uis), path="core")
+        rep, conv, k_exit, conv_at = fn(ks, ucie_line_uis, device_line_uis)
+        jax.block_until_ready(rep)
     _record_adaptive("flitsim.pipelining", horizon, chunk, k_exit, conv_at,
                      0,                 # exits only converged / at horizon
                      engine="xla", launches=1,
@@ -1547,7 +1584,7 @@ def _run_symmetric_trace(pstack, xs, ys, bls, cycles: int,
         "flitsim.symmetric", ("trace", P, T, N, cycles) + sim.key(),
         functools.partial(_symmetric_trace_grid, n_phases=N,
                           cycles=cycles),
-        (pstack, xs, ys, bls))
+        (pstack, xs, ys, bls), path="trace")
     rep = fn(pstack, xs, ys, bls)
     _record_trace("flitsim.symmetric", N, cycles, P * T)
     return rep
@@ -1561,7 +1598,7 @@ def _run_asymmetric_trace(pstack, xs, ys, cycles: int, sim: SimConfig):
         "flitsim.asymmetric", ("trace", P, T, N, cycles) + sim.key(),
         functools.partial(_asymmetric_trace_grid, n_phases=N,
                           cycles=cycles),
-        (pstack, xs, ys))
+        (pstack, xs, ys), path="trace")
     rep = fn(pstack, xs, ys)
     _record_trace("flitsim.asymmetric", N, cycles, P * T)
     return rep
@@ -1598,49 +1635,52 @@ def simulate_grid(protocols: Sequence[str], x, y, backlogs, *,
     if unknown:
         raise ValueError(f"unknown protocol keys {unknown}; "
                          f"choose from {sorted(SIMULATORS)}")
-    perts = [dict(p) for p in (perturbations or [{}])]
-    active_fields: set = set()
-    if any(k in SYMMETRIC_PARAMS for k in keys):
-        active_fields |= {f.name
-                          for f in dataclasses.fields(SymmetricFlitParams)}
-    if any(k in ASYMMETRIC_PARAMS for k in keys):
-        active_fields |= {f.name
-                          for f in dataclasses.fields(AsymmetricLaneParams)}
-    for p in perts:
-        _check_perturbation(p)
-        # a perturbation that touches NO field of the selected families
-        # would silently produce a baseline row labeled as perturbed
-        if p and not set(p) & active_fields:
-            raise ValueError(
-                f"perturbation {p} applies to no parameter of the selected "
-                f"protocols {keys}; applicable fields: "
-                f"{sorted(active_fields)}")
-    x = _f32(np.asarray(x).reshape(-1))
-    y = _f32(np.asarray(y).reshape(-1))
-    b = _f32(np.asarray(backlogs).reshape(-1))
-    n_q, n_b, n_m = len(perts), b.shape[0], x.shape[0]
-
-    per_key: Dict[str, jnp.ndarray] = {}            # key -> [Q, B, M]
     sym_keys = [k for k in keys if k in SYMMETRIC_PARAMS]
-    if sym_keys:
-        pstack = SymmetricFlitParams.stack(
-            [SYMMETRIC_PARAMS[k].perturbed(p) for p in perts
-             for k in sym_keys])
-        grid = _run_symmetric(pstack, x, y, b, int(n_flits), sim=sim)
-        grid = grid.reshape((n_q, len(sym_keys), n_b, n_m))
-        for i, k in enumerate(sym_keys):
-            per_key[k] = grid[:, i]
     asym_keys = [k for k in keys if k in ASYMMETRIC_PARAMS]
-    if asym_keys:
-        pstack = AsymmetricLaneParams.stack(
+    with TraceAnnotation("repro.space.lower"):
+        perts = [dict(p) for p in (perturbations or [{}])]
+        active_fields: set = set()
+        if sym_keys:
+            active_fields |= {
+                f.name for f in dataclasses.fields(SymmetricFlitParams)}
+        if asym_keys:
+            active_fields |= {
+                f.name for f in dataclasses.fields(AsymmetricLaneParams)}
+        for p in perts:
+            _check_perturbation(p)
+            # a perturbation that touches NO field of the selected families
+            # would silently produce a baseline row labeled as perturbed
+            if p and not set(p) & active_fields:
+                raise ValueError(
+                    f"perturbation {p} applies to no parameter of the "
+                    f"selected protocols {keys}; applicable fields: "
+                    f"{sorted(active_fields)}")
+        x = _f32(np.asarray(x).reshape(-1))
+        y = _f32(np.asarray(y).reshape(-1))
+        b = _f32(np.asarray(backlogs).reshape(-1))
+        n_q, n_b, n_m = len(perts), b.shape[0], x.shape[0]
+        sym_stack = (SymmetricFlitParams.stack(
+            [SYMMETRIC_PARAMS[k].perturbed(p) for p in perts
+             for k in sym_keys]) if sym_keys else None)
+        asym_stack = (AsymmetricLaneParams.stack(
             [ASYMMETRIC_PARAMS[k].perturbed(p) for p in perts
-             for k in asym_keys])
-        grid = _run_asymmetric(pstack, x, y, int(n_accesses), sim=sim)
-        grid = grid.reshape((n_q, len(asym_keys), n_m))
-        for i, k in enumerate(asym_keys):
-            per_key[k] = jnp.broadcast_to(grid[:, i, None, :],
-                                          (n_q, n_b, n_m))
-    return jnp.stack([per_key[k] for k in keys], axis=1)   # [Q, P, B, M]
+             for k in asym_keys]) if asym_keys else None)
+    sym_grid = (_run_symmetric(sym_stack, x, y, b, int(n_flits), sim=sim)
+                if sym_keys else None)
+    asym_grid = (_run_asymmetric(asym_stack, x, y, int(n_accesses), sim=sim)
+                 if asym_keys else None)
+    with TraceAnnotation("repro.space.assemble"):
+        per_key: Dict[str, jnp.ndarray] = {}        # key -> [Q, B, M]
+        if sym_keys:
+            grid = sym_grid.reshape((n_q, len(sym_keys), n_b, n_m))
+            for i, k in enumerate(sym_keys):
+                per_key[k] = grid[:, i]
+        if asym_keys:
+            grid = asym_grid.reshape((n_q, len(asym_keys), n_m))
+            for i, k in enumerate(asym_keys):
+                per_key[k] = jnp.broadcast_to(grid[:, i, None, :],
+                                              (n_q, n_b, n_m))
+        return jnp.stack([per_key[k] for k in keys], axis=1)  # [Q, P, B, M]
 
 
 def simulate_trace_grid(protocols: Sequence[str], xs, ys, backlogs, *,

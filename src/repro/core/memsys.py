@@ -223,7 +223,7 @@ def run_catalog_program(items: Tuple[Tuple[str, MemorySystem], ...],
 
     prog = cached_program("memsys.catalog",
                           (items, x.shape, y.shape, sl.shape),
-                          fn, (x, y, sl))
+                          fn, (x, y, sl), path="grid")
     return prog(x, y, sl)
 
 
@@ -302,7 +302,7 @@ def run_approach_phys_program(phys, x, y):
         return lin, areal, pjb
 
     prog = cached_program("memsys.approach", (phys, x.shape, y.shape),
-                          fn, (x, y))
+                          fn, (x, y), path="grid")
     return prog(x, y)
 
 

@@ -321,7 +321,7 @@ MAX_PROGRAMS_PER_FAMILY = 32
 
 
 def cached_program(family: str, key: Tuple, build_fn: Callable,
-                   example_args: Tuple):
+                   example_args: Tuple, path: Optional[str] = None):
     """Return a compiled executable for ``build_fn`` memoized on
     ``(family, *key)``.
 
@@ -330,6 +330,13 @@ def cached_program(family: str, key: Tuple, build_fn: Callable,
     request.  A second identically-keyed request is a cache hit and runs
     the warm executable with zero retracing.  Each family keeps
     at most :data:`MAX_PROGRAMS_PER_FAMILY` executables (FIFO eviction).
+
+    ``path`` names the program after its family and the engine path that
+    built it: the module is ``jit_<family>_<path>`` with dots as
+    underscores (``jit_flitsim_symmetric_probe``), so a device trace says
+    which engine ran.  Without it the program keeps ``build_fn``'s own
+    name.  A miss compiles inside a ``repro.compile`` span that carries
+    the family and the key, so a traced window shows what recompiled.
     """
     stats = _FAMILY_STATS.setdefault(family, CacheStats())
     full_key = (family,) + tuple(key)
@@ -338,12 +345,25 @@ def cached_program(family: str, key: Tuple, build_fn: Callable,
         stats.hits += 1
         return entry
     stats.misses += 1
-    entry = jax.jit(build_fn).lower(*example_args).compile()
+    if path is not None:
+        build_fn = _named(build_fn, f"{family}_{path}".replace(".", "_"))
+    with jax.profiler.TraceAnnotation("repro.compile", family=family,
+                                      key=repr(key)):
+        entry = jax.jit(build_fn).lower(*example_args).compile()
     family_keys = [k for k in _PROGRAMS if k[0] == family]
     if len(family_keys) >= MAX_PROGRAMS_PER_FAMILY:
         del _PROGRAMS[family_keys[0]]        # dict order == insertion order
     _PROGRAMS[full_key] = entry
     return entry
+
+
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under the function name ``jax.jit`` gives its module (a
+    ``functools.partial`` would compile as ``jit__unknown``)."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
 
 
 def cache_stats(families: Optional[Sequence[str]] = None) -> CacheStats:
@@ -1203,33 +1223,34 @@ class DesignSpace:
         bit-identical to the materialized path) instead of a
         :class:`SpaceResult`.
         """
-        if stream is not None:
-            from repro.core import streaming
-            return streaming.stream_evaluate(
-                self, metrics, sim if sim is not None else self.sim,
-                stream)
-        cfg = sim if sim is not None else self.sim
-        wanted = tuple(metrics) if metrics is not None else \
-            self._default_metrics()
-        known = (ANALYTIC_METRICS + SYSTEM_METRICS + SIM_METRICS
-                 + SIM_PHY_METRICS + APPROACH_METRICS + PIPELINE_METRICS
-                 + TRACE_METRICS + TRACE_PHY_METRICS)
-        unknown = [m for m in wanted if m not in known]
-        if unknown:
-            raise ValueError(f"unknown metrics {unknown}; choose from "
-                             f"{known}")
-        arrays: Dict[str, SpaceArray] = {}
-        if any(m in wanted for m in ANALYTIC_METRICS + SYSTEM_METRICS):
-            arrays.update(self._eval_catalog(wanted))
-        if any(m in wanted for m in APPROACH_METRICS):
-            arrays.update(self._eval_approaches(wanted))
-        if any(m in wanted for m in SIM_METRICS + SIM_PHY_METRICS):
-            arrays.update(self._eval_sim(wanted, cfg))
-        if any(m in wanted for m in TRACE_METRICS + TRACE_PHY_METRICS):
-            arrays.update(self._eval_trace(wanted, cfg))
-        if any(m in wanted for m in PIPELINE_METRICS):
-            arrays.update(self._eval_pipelining(wanted, cfg))
-        return SpaceResult(axes=self.axes, arrays=arrays, sim=cfg)
+        with jax.profiler.TraceAnnotation("repro.space.evaluate"):
+            if stream is not None:
+                from repro.core import streaming
+                return streaming.stream_evaluate(
+                    self, metrics, sim if sim is not None else self.sim,
+                    stream)
+            cfg = sim if sim is not None else self.sim
+            wanted = tuple(metrics) if metrics is not None else \
+                self._default_metrics()
+            known = (ANALYTIC_METRICS + SYSTEM_METRICS + SIM_METRICS
+                     + SIM_PHY_METRICS + APPROACH_METRICS + PIPELINE_METRICS
+                     + TRACE_METRICS + TRACE_PHY_METRICS)
+            unknown = [m for m in wanted if m not in known]
+            if unknown:
+                raise ValueError(f"unknown metrics {unknown}; choose from "
+                                 f"{known}")
+            arrays: Dict[str, SpaceArray] = {}
+            if any(m in wanted for m in ANALYTIC_METRICS + SYSTEM_METRICS):
+                arrays.update(self._eval_catalog(wanted))
+            if any(m in wanted for m in APPROACH_METRICS):
+                arrays.update(self._eval_approaches(wanted))
+            if any(m in wanted for m in SIM_METRICS + SIM_PHY_METRICS):
+                arrays.update(self._eval_sim(wanted, cfg))
+            if any(m in wanted for m in TRACE_METRICS + TRACE_PHY_METRICS):
+                arrays.update(self._eval_trace(wanted, cfg))
+            if any(m in wanted for m in PIPELINE_METRICS):
+                arrays.update(self._eval_pipelining(wanted, cfg))
+            return SpaceResult(axes=self.axes, arrays=arrays, sim=cfg)
 
     def _perturbations(self) -> List[Dict[str, float]]:
         cp_ax = self.axes.get("catalog_param")
@@ -1361,82 +1382,84 @@ class DesignSpace:
 
     def _eval_sim(self, wanted, sim: SimConfig) -> Dict[str, SpaceArray]:
         from repro.core import flitsim
-        keys = self._sim_protocols()
-        x, y, mix_dims = self._mix_arrays()
-        mix_shape = x.shape
-        xf = x.reshape(-1)
-        yf = y.reshape(-1)
-        if np.any(xf < 0) or np.any(yf < 0) or np.any(xf + yf <= 0):
-            raise ValueError("invalid traffic mix in the lowered grid")
-        bl_ax = self.axes.get("backlog")
-        backlogs = np.asarray(bl_ax.values if bl_ax is not None
-                              else [self.default_backlog], np.float32)
-        pert_ax = self.axes.get("protocol_param")
-        perts = ([dict(p) for _, p in pert_ax.values]
-                 if pert_ax is not None else [{}])
-        eff = np.asarray(flitsim.simulate_grid(
+        with jax.profiler.TraceAnnotation("repro.space.lower"):
+            keys = self._sim_protocols()
+            x, y, mix_dims = self._mix_arrays()
+            mix_shape = x.shape
+            xf = x.reshape(-1)
+            yf = y.reshape(-1)
+            if np.any(xf < 0) or np.any(yf < 0) or np.any(xf + yf <= 0):
+                raise ValueError("invalid traffic mix in the lowered grid")
+            bl_ax = self.axes.get("backlog")
+            backlogs = np.asarray(bl_ax.values if bl_ax is not None
+                                  else [self.default_backlog], np.float32)
+            pert_ax = self.axes.get("protocol_param")
+            perts = ([dict(p) for _, p in pert_ax.values]
+                     if pert_ax is not None else [{}])
+        eff = flitsim.simulate_grid(
             keys, xf, yf, backlogs, perturbations=perts,
-            n_flits=self.n_flits, n_accesses=self.n_accesses, sim=sim))
-        # eff: [Q, P, B, Mf] -> named dims, dropping absent axes
-        eff = eff.reshape(eff.shape[:3] + mix_shape)
-        dims: List[str] = ["protocol_param", "protocol", "backlog"]
-        coords: List[Tuple] = [
-            pert_ax.labels if pert_ax is not None else ("baseline",),
-            keys,
-            bl_ax.labels if bl_ax is not None else (self.default_backlog,)]
-        dims += list(mix_dims)
-        coords += [self.axes[d].labels for d in mix_dims]
-        if pert_ax is None:
-            eff = eff[0]
-            dims, coords = dims[1:], coords[1:]
-        if bl_ax is None:
-            ax_b = dims.index("backlog")
-            eff = np.take(eff, 0, axis=ax_b)
-            del dims[ax_b], coords[ax_b]
-        if not mix_dims:                     # placeholder 100R0W point
-            eff = eff[..., 0]
-        out: Dict[str, SpaceArray] = {}
-        if "sim_efficiency" in wanted:
-            out["sim_efficiency"] = SpaceArray(
-                tuple(dims), tuple(coords), np.asarray(eff))
-        if "sim_bandwidth_gbs" in wanted:
-            phy_ax = self.axes.get("phy")
-            if phy_ax is not None:
-                phys = list(phy_ax.values)
-            elif self.phy is not None:
-                phys = [self.phy]
-            else:
-                raise ValueError(
-                    "the 'sim_bandwidth_gbs' metric threads the PHY's raw "
-                    "link bandwidth into the simulated efficiency — add a "
-                    "'phy' axis or pass DesignSpace(phy=...)")
-            raw = np.asarray([p.raw_bandwidth_gbs for p in phys],
-                             np.float32)
-            ax_p = dims.index("protocol")
-            v = (np.expand_dims(np.asarray(eff), ax_p + 1)
-                 * raw.reshape((len(raw),)
-                               + (1,) * (np.ndim(eff) - ax_p - 1)))
-            bdims = tuple(dims[:ax_p + 1]) + ("phy",) \
-                + tuple(dims[ax_p + 1:])
-            bcoords = tuple(coords[:ax_p + 1]) \
-                + (tuple(p.name for p in phys),) \
-                + tuple(coords[ax_p + 1:])
-            if phy_ax is None:          # DesignSpace(phy=...): no phy dim
-                v = np.take(v, 0, axis=ax_p + 1)
-                bdims = bdims[:ax_p + 1] + bdims[ax_p + 2:]
-                bcoords = bcoords[:ax_p + 1] + bcoords[ax_p + 2:]
-            out["sim_bandwidth_gbs"] = SpaceArray(bdims, bcoords, v)
-        if "analytic_efficiency" in wanted:
-            an = np.stack([np.asarray(flitsim.ANALYTIC[k].bw_eff(xf, yf),
-                                      np.float32) for k in keys])
-            an = an.reshape((len(keys),) + mix_shape)
-            adims = ("protocol",) + mix_dims
-            acoords = (keys,) + tuple(self.axes[d].labels
-                                      for d in mix_dims)
-            if not mix_dims:
-                an = an[..., 0]
-            out["analytic_efficiency"] = SpaceArray(adims, acoords, an)
-        return out
+            n_flits=self.n_flits, n_accesses=self.n_accesses, sim=sim)
+        with jax.profiler.TraceAnnotation("repro.space.assemble"):
+            # eff: [Q, P, B, Mf] -> named dims, dropping absent axes
+            eff = np.asarray(eff).reshape(eff.shape[:3] + mix_shape)
+            dims: List[str] = ["protocol_param", "protocol", "backlog"]
+            coords: List[Tuple] = [
+                pert_ax.labels if pert_ax is not None else ("baseline",),
+                keys,
+                bl_ax.labels if bl_ax is not None else (self.default_backlog,)]
+            dims += list(mix_dims)
+            coords += [self.axes[d].labels for d in mix_dims]
+            if pert_ax is None:
+                eff = eff[0]
+                dims, coords = dims[1:], coords[1:]
+            if bl_ax is None:
+                ax_b = dims.index("backlog")
+                eff = np.take(eff, 0, axis=ax_b)
+                del dims[ax_b], coords[ax_b]
+            if not mix_dims:                     # placeholder 100R0W point
+                eff = eff[..., 0]
+            out: Dict[str, SpaceArray] = {}
+            if "sim_efficiency" in wanted:
+                out["sim_efficiency"] = SpaceArray(
+                    tuple(dims), tuple(coords), np.asarray(eff))
+            if "sim_bandwidth_gbs" in wanted:
+                phy_ax = self.axes.get("phy")
+                if phy_ax is not None:
+                    phys = list(phy_ax.values)
+                elif self.phy is not None:
+                    phys = [self.phy]
+                else:
+                    raise ValueError(
+                        "the 'sim_bandwidth_gbs' metric threads the PHY's raw "
+                        "link bandwidth into the simulated efficiency — add a "
+                        "'phy' axis or pass DesignSpace(phy=...)")
+                raw = np.asarray([p.raw_bandwidth_gbs for p in phys],
+                                 np.float32)
+                ax_p = dims.index("protocol")
+                v = (np.expand_dims(np.asarray(eff), ax_p + 1)
+                     * raw.reshape((len(raw),)
+                                   + (1,) * (np.ndim(eff) - ax_p - 1)))
+                bdims = tuple(dims[:ax_p + 1]) + ("phy",) \
+                    + tuple(dims[ax_p + 1:])
+                bcoords = tuple(coords[:ax_p + 1]) \
+                    + (tuple(p.name for p in phys),) \
+                    + tuple(coords[ax_p + 1:])
+                if phy_ax is None:          # DesignSpace(phy=...): no phy dim
+                    v = np.take(v, 0, axis=ax_p + 1)
+                    bdims = bdims[:ax_p + 1] + bdims[ax_p + 2:]
+                    bcoords = bcoords[:ax_p + 1] + bcoords[ax_p + 2:]
+                out["sim_bandwidth_gbs"] = SpaceArray(bdims, bcoords, v)
+            if "analytic_efficiency" in wanted:
+                an = np.stack([np.asarray(flitsim.ANALYTIC[k].bw_eff(xf, yf),
+                                          np.float32) for k in keys])
+                an = an.reshape((len(keys),) + mix_shape)
+                adims = ("protocol",) + mix_dims
+                acoords = (keys,) + tuple(self.axes[d].labels
+                                          for d in mix_dims)
+                if not mix_dims:
+                    an = an[..., 0]
+                out["analytic_efficiency"] = SpaceArray(adims, acoords, an)
+            return out
 
     def _eval_trace(self, wanted, sim: SimConfig) -> Dict[str, SpaceArray]:
         from repro.core import flitsim

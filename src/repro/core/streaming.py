@@ -57,6 +57,7 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import PartitionSpec
 
 from repro.core import space as space_mod
@@ -373,47 +374,50 @@ def _stream_sim(space, metric: str, sim, stream) -> StreamResult:
     def retire():
         # the ONE audited host sync of the dispatch loop: the OLDEST
         # in-flight chunk blocks here, so folds run in sequential order
-        lo, live, (codes, counts, best) = inflight.popleft()
-        # repro-lint: disable=RL004  (audited FIFO retire sync)
-        codes_np, counts_np, best_np = (np.asarray(codes),
-                                        np.asarray(counts),
-                                        np.asarray(best))
-        codes_out[lo:lo + live] = codes_np[:live]
-        counts_total[...] += counts_np.astype(np.int64)
-        np.maximum(best_total, best_np.astype(np.float64),
-                   out=best_total)
+        with TraceAnnotation("repro.stream.retire"):
+            lo, live, (codes, counts, best) = inflight.popleft()
+            # repro-lint: disable=RL004  (audited FIFO retire sync)
+            codes_np, counts_np, best_np = (np.asarray(codes),
+                                            np.asarray(counts),
+                                            np.asarray(best))
+            codes_out[lo:lo + live] = codes_np[:live]
+            counts_total[...] += counts_np.astype(np.int64)
+            np.maximum(best_total, best_np.astype(np.float64),
+                       out=best_total)
 
     for t in range(n_dispatch):
-        m0 = time.perf_counter()
-        lo = t * step
-        ids, valid, live = _chunk_ids(lo, step, n_cells)
-        multi = np.unravel_index(ids, shape_perm)
-        by_dim = {dims_all[order[j]]: multi[j]
-                  for j in range(len(order))}
-        q_idx = by_dim["protocol_param"]
-        b_idx = by_dim["backlog"]
-        if mix_dims:
-            m_idx = np.ravel_multi_index(
-                tuple(by_dim[d] for d in mix_dims), mix_shape)
-        else:
-            m_idx = np.zeros(step, np.int64)
-        rows_sym = (q_idx[:, None] * p_sym + a_sym).reshape(-1)
-        rows_asym = (q_idx[:, None] * p_asym + a_asym).reshape(-1)
-        args = (
-            jax.tree_util.tree_map(lambda l: l[rows_sym], sym_host),
-            np.repeat(xf[m_idx], p_sym), np.repeat(yf[m_idx], p_sym),
-            np.repeat(backlogs[b_idx], p_sym),
-            jax.tree_util.tree_map(lambda l: l[rows_asym], asym_host),
-            np.repeat(xf[m_idx], p_asym), np.repeat(yf[m_idx], p_asym),
-            raw, valid)
-        dm = time.perf_counter() - m0
+        with TraceAnnotation("repro.stream.marshal"):
+            m0 = time.perf_counter()
+            lo = t * step
+            ids, valid, live = _chunk_ids(lo, step, n_cells)
+            multi = np.unravel_index(ids, shape_perm)
+            by_dim = {dims_all[order[j]]: multi[j]
+                      for j in range(len(order))}
+            q_idx = by_dim["protocol_param"]
+            b_idx = by_dim["backlog"]
+            if mix_dims:
+                m_idx = np.ravel_multi_index(
+                    tuple(by_dim[d] for d in mix_dims), mix_shape)
+            else:
+                m_idx = np.zeros(step, np.int64)
+            rows_sym = (q_idx[:, None] * p_sym + a_sym).reshape(-1)
+            rows_asym = (q_idx[:, None] * p_asym + a_asym).reshape(-1)
+            args = (
+                jax.tree_util.tree_map(lambda l: l[rows_sym], sym_host),
+                np.repeat(xf[m_idx], p_sym), np.repeat(yf[m_idx], p_sym),
+                np.repeat(backlogs[b_idx], p_sym),
+                jax.tree_util.tree_map(lambda l: l[rows_asym], asym_host),
+                np.repeat(xf[m_idx], p_asym), np.repeat(yf[m_idx], p_asym),
+                raw, valid)
+            dm = time.perf_counter() - m0
         marshal_s += dm
         if inflight:                # marshalled while a chunk was in flight
             overlap_s += dm
         if prog is None:
             prog = space_mod.cached_program("stream.sim", key, chunk_fn,
                                             args)
-        inflight.append((lo, live, prog(*args)))
+        with TraceAnnotation("repro.stream.dispatch", index=t):
+            inflight.append((lo, live, prog(*args)))
         while len(inflight) >= prefetch:
             retire()
     while inflight:
@@ -433,8 +437,9 @@ def _stream_sim(space, metric: str, sim, stream) -> StreamResult:
             ("phy", has_phy_dim, phy_names),
             ("backlog", bl_ax is not None, bl_labels)]
     full += [(d, True, tuple(space.axes[d].labels)) for d in mix_dims]
-    winners = _winner_array(codes_out, shape_perm, order, full,
-                            np.asarray(keys, dtype=object))
+    with TraceAnnotation("repro.stream.winners"):
+        winners = _winner_array(codes_out, shape_perm, order, full,
+                                np.asarray(keys, dtype=object))
     per_label = counts_total.sum(axis=0)
     return StreamResult(
         metric=metric, reduce_dim="protocol", mode="max", labels=keys,
@@ -602,42 +607,45 @@ def _stream_catalog(space, metric: str, sim, stream) -> StreamResult:
     def retire():
         # the ONE audited host sync of the dispatch loop: the OLDEST
         # in-flight chunk blocks here, so folds run in sequential order
-        lo, live, (codes, counts, best, none_ct) = inflight.popleft()
-        # repro-lint: disable=RL004  (audited FIFO retire sync)
-        codes_np, counts_np, best_np, none_np = (
-            np.asarray(codes), np.asarray(counts), np.asarray(best),
-            np.asarray(none_ct))
-        codes_out[lo:lo + live] = codes_np[:live]
-        counts_total[...] += counts_np.astype(np.int64)
-        none_total[...] += np.int64(none_np)
-        acc(best_total, best_np.astype(np.float64), out=best_total)
+        with TraceAnnotation("repro.stream.retire"):
+            lo, live, (codes, counts, best, none_ct) = inflight.popleft()
+            # repro-lint: disable=RL004  (audited FIFO retire sync)
+            codes_np, counts_np, best_np, none_np = (
+                np.asarray(codes), np.asarray(counts), np.asarray(best),
+                np.asarray(none_ct))
+            codes_out[lo:lo + live] = codes_np[:live]
+            counts_total[...] += counts_np.astype(np.int64)
+            none_total[...] += np.int64(none_np)
+            acc(best_total, best_np.astype(np.float64), out=best_total)
 
     for t in range(n_dispatch):
-        m0 = time.perf_counter()
-        lo = t * step
-        ids, valid, live = _chunk_ids(lo, step, n_cells)
-        multi = np.unravel_index(ids, shape_perm)
-        by_dim = {dims_all[order[j]]: multi[j]
-                  for j in range(len(order))}
-        l_idx = by_dim["shoreline_mm"]
-        if mix_dims:
-            m_idx = np.ravel_multi_index(
-                tuple(by_dim[d] for d in mix_dims), mix_shape)
-        else:
-            m_idx = np.zeros(step, np.int64)
-        k_idx = by_dim[knee_dim] if knee_dim is not None else \
-            np.zeros(step, np.int64)
-        adm = (static[None, :]
-               & knee_adm[:, k_idx].T).astype(np.int32)     # [step, S]
-        args = (xf[m_idx], yf[m_idx], sls[l_idx], adm, thr, valid)
-        dm = time.perf_counter() - m0
+        with TraceAnnotation("repro.stream.marshal"):
+            m0 = time.perf_counter()
+            lo = t * step
+            ids, valid, live = _chunk_ids(lo, step, n_cells)
+            multi = np.unravel_index(ids, shape_perm)
+            by_dim = {dims_all[order[j]]: multi[j]
+                      for j in range(len(order))}
+            l_idx = by_dim["shoreline_mm"]
+            if mix_dims:
+                m_idx = np.ravel_multi_index(
+                    tuple(by_dim[d] for d in mix_dims), mix_shape)
+            else:
+                m_idx = np.zeros(step, np.int64)
+            k_idx = by_dim[knee_dim] if knee_dim is not None else \
+                np.zeros(step, np.int64)
+            adm = (static[None, :]
+                   & knee_adm[:, k_idx].T).astype(np.int32)  # [step, S]
+            args = (xf[m_idx], yf[m_idx], sls[l_idx], adm, thr, valid)
+            dm = time.perf_counter() - m0
         marshal_s += dm
         if inflight:                # marshalled while a chunk was in flight
             overlap_s += dm
         if prog is None:
             prog = space_mod.cached_program("stream.catalog", key,
                                             chunk_fn, args)
-        inflight.append((lo, live, prog(*args)))
+        with TraceAnnotation("repro.stream.dispatch", index=t):
+            inflight.append((lo, live, prog(*args)))
         while len(inflight) >= prefetch:
             retire()
     while inflight:
@@ -654,8 +662,10 @@ def _stream_catalog(space, metric: str, sim, stream) -> StreamResult:
     sl_labels = (tuple(sl_ax.labels) if sl_ax is not None
                  else (space.default_shoreline_mm,))
     full += [("shoreline_mm", sl_ax is not None, sl_labels)]
-    winners = _winner_array(codes_out, shape_perm, order, full,
-                            np.asarray(keys + ("(none)",), dtype=object))
+    with TraceAnnotation("repro.stream.winners"):
+        winners = _winner_array(codes_out, shape_perm, order, full,
+                                np.asarray(keys + ("(none)",),
+                                           dtype=object))
     win_counts = {k: int(counts_total[i]) for i, k in enumerate(keys)}
     if cons is not None:
         win_counts["(none)"] = int(none_total)

@@ -180,7 +180,6 @@ class TestPallasEngineMatches:
             assert v["engine"] == "pallas", fam
             assert v["launches"] >= 1
             assert v["elapsed_s"] > 0.0
-            assert v["cycles_per_sec_per_cell"] > 0.0
 
 
 def _true_period(x, y):
