@@ -153,6 +153,24 @@ class _Stackable:
         names = [f.name for f in dataclasses.fields(cls)]
         return cls(*[_f32([getattr(p, n) for p in params]) for n in names])
 
+    @classmethod
+    def perturbed_stack(cls, bases: Sequence["_Stackable"],
+                        perts: Sequence[Mapping[str, float]]):
+        """``cls.stack([b.perturbed(p) for p in perts for b in bases])``
+        (row ``q * len(bases) + k``), built a field at a time: each value
+        is the same float64 product of base and scale as
+        :func:`apply_perturbation` forms, rounded once to f32, with no
+        parameter object per row."""
+        cols = []
+        for f in dataclasses.fields(cls):
+            base = np.asarray([float(getattr(b, f.name)) for b in bases],
+                              np.float64)
+            scale = np.asarray([float(p.get(f.name, 1.0)) for p in perts],
+                               np.float64)
+            cols.append(_f32((scale[:, None] * base[None, :]).reshape(-1)
+                             .astype(np.float32)))
+        return cls(*cols)
+
     def perturbed(self, pert: Mapping[str, float]) -> "_Stackable":
         """Scale the named fields multiplicatively (fields this family
         doesn't have are ignored — validated upstream)."""
@@ -993,19 +1011,30 @@ def _record_adaptive(family: str, horizon: int, chunk: int, k_exit,
 def _record_stream(family: str, *, dispatches: int, prefetch: int,
                    pad_cells: int, overlap_frac: float, cells: int,
                    elapsed_s: Optional[float] = None,
-                   marshal_s: Optional[float] = None) -> None:
+                   marshal_s: Optional[float] = None,
+                   dispatch_arrays: Optional[int] = None,
+                   dispatch_bytes: Optional[int] = None,
+                   resident_bytes: Optional[int] = None) -> None:
     """Telemetry for a streaming dispatch run (``stream.*`` families):
     dispatch count, bounded in-flight depth, replicated pad-cell total,
     and the marshal-vs-device overlap fraction (how much of the host's
     index-marshalling wall time ran while a previous chunk was still in
     flight — the async win over the strictly sequential loop).
     ``marshal_s`` is the total host marshalling wall time, so
-    ``marshal_s / elapsed_s`` bounds the async win available."""
+    ``marshal_s / elapsed_s`` bounds the async win available.
+
+    The simulated stream keeps its parameter stacks and PHY bandwidths
+    resident on the device for the whole query (``resident_bytes``,
+    placed once) and sends each dispatch only its cells' packed indices:
+    ``dispatch_arrays`` host arrays of ``dispatch_bytes`` in all."""
     _LAST_RUN_INFO[family] = {
         "mode": "stream", "dispatches": int(dispatches),
         "prefetch": int(prefetch), "pad_cells": int(pad_cells),
         "overlap_frac": float(overlap_frac), "cells": int(cells),
         "elapsed_s": elapsed_s, "marshal_s": marshal_s,
+        "dispatch_arrays": dispatch_arrays,
+        "dispatch_bytes": dispatch_bytes,
+        "resident_bytes": resident_bytes,
     }
 
 
@@ -1659,12 +1688,12 @@ def simulate_grid(protocols: Sequence[str], x, y, backlogs, *,
         y = _f32(np.asarray(y).reshape(-1))
         b = _f32(np.asarray(backlogs).reshape(-1))
         n_q, n_b, n_m = len(perts), b.shape[0], x.shape[0]
-        sym_stack = (SymmetricFlitParams.stack(
-            [SYMMETRIC_PARAMS[k].perturbed(p) for p in perts
-             for k in sym_keys]) if sym_keys else None)
-        asym_stack = (AsymmetricLaneParams.stack(
-            [ASYMMETRIC_PARAMS[k].perturbed(p) for p in perts
-             for k in asym_keys]) if asym_keys else None)
+        sym_stack = (SymmetricFlitParams.perturbed_stack(
+            [SYMMETRIC_PARAMS[k] for k in sym_keys], perts)
+            if sym_keys else None)
+        asym_stack = (AsymmetricLaneParams.perturbed_stack(
+            [ASYMMETRIC_PARAMS[k] for k in asym_keys], perts)
+            if asym_keys else None)
     sym_grid = (_run_symmetric(sym_stack, x, y, b, int(n_flits), sim=sim)
                 if sym_keys else None)
     asym_grid = (_run_asymmetric(asym_stack, x, y, int(n_accesses), sim=sim)
@@ -1740,9 +1769,8 @@ def simulate_trace_grid(protocols: Sequence[str], xs, ys, backlogs, *,
     sym_keys = [k for k in keys if k in SYMMETRIC_PARAMS]
     if sym_keys:
         cycles = int(sim.trace_cycles or n_flits)
-        pstack = SymmetricFlitParams.stack(
-            [SYMMETRIC_PARAMS[k].perturbed(p) for p in perts
-             for k in sym_keys])
+        pstack = SymmetricFlitParams.perturbed_stack(
+            [SYMMETRIC_PARAMS[k] for k in sym_keys], perts)
         grid = _run_symmetric_trace(pstack, xs, ys, bls, cycles, sim)
         grid = grid.reshape((n_q, len(sym_keys), n_t, n_p))
         for i, k in enumerate(sym_keys):
@@ -1750,9 +1778,8 @@ def simulate_trace_grid(protocols: Sequence[str], xs, ys, backlogs, *,
     asym_keys = [k for k in keys if k in ASYMMETRIC_PARAMS]
     if asym_keys:
         cycles = int(sim.trace_cycles or n_accesses)
-        pstack = AsymmetricLaneParams.stack(
-            [ASYMMETRIC_PARAMS[k].perturbed(p) for p in perts
-             for k in asym_keys])
+        pstack = AsymmetricLaneParams.perturbed_stack(
+            [ASYMMETRIC_PARAMS[k] for k in asym_keys], perts)
         grid = _run_asymmetric_trace(pstack, xs, ys, cycles, sim)
         grid = grid.reshape((n_q, len(asym_keys), n_t, n_p))
         for i, k in enumerate(asym_keys):

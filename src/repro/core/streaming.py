@@ -33,17 +33,30 @@ early-exit schedule depends on batch shape, which would break the
 bit-identity contract across chunk sizes); control cost via
 ``DesignSpace(n_flits=..., n_accesses=...)`` instead.
 
+Resident tables, packed indices: the simulated stream places its
+perturbation-major parameter stacks and the PHY bandwidths on the device
+once per query (replicated over the mesh), and each dispatch sends only
+what differs per cell, packed into two host arrays sharded on
+``chunks``: an int32 ``[step, 2]`` of (perturbation index, valid) and a
+float32 ``[step, 3]`` of (x, y, backlog).  The chunk program gathers each
+cell's parameter rows on the device; its input shapes follow the
+perturbation count, never the backlog or mix axes' lengths, so one
+program serves every space over the same perturbations (and each
+distinct perturbation count compiles its own).
+
 Async double-buffered dispatch: the per-dispatch loop marshals chunk
-``t+1``'s cell indices (pure numpy — ``_chunk_ids`` plus the
-mix/backlog/perturbation gathers) while up to ``StreamConfig.prefetch``
-earlier chunks are still in flight on the device, and blocks only when
-the in-flight window is full.  Results retire strictly FIFO, so the
+``t+1``'s cell indices (pure numpy — ``_chunk_ids`` plus the mix and
+backlog value gathers) while up to ``StreamConfig.prefetch`` earlier
+chunks are still in flight on the device, and blocks only when the
+in-flight window is full.  Results retire strictly FIFO, so the
 running host-side folds (winner-code scatter, count sums, best maxima)
 execute in EXACTLY the order of the sequential loop — ``prefetch=1``
 reduces to the sequential schedule, and every depth produces
 bit-identical ``StreamResult`` contents.  The FIFO retire is the one
 audited host sync of the loop (see the RL004 suppressions); per-run
-dispatch/overlap telemetry lands in
+dispatch/overlap telemetry, with the host arrays and bytes a dispatch
+sends and the bytes placed once per query (``dispatch_arrays``,
+``dispatch_bytes``, ``resident_bytes``), lands in
 ``flitsim.last_run_info()["stream.*"]``.
 """
 from __future__ import annotations
@@ -58,7 +71,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.profiler import TraceAnnotation
-from jax.sharding import PartitionSpec
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.core import space as space_mod
 
@@ -223,6 +236,70 @@ def _winner_array(codes: np.ndarray, shape_perm, order, full, labels_ext):
 # =========================================================================
 
 
+def _sim_chunk_fn(mesh, keys, chunk: int, n_flits: int, n_accesses: int):
+    """The ``stream.sim`` chunk program: ``(sym_tab, asym_tab, raw,
+    qv, xyb) -> (codes, counts, best)`` over ``chunk`` cells a device.
+
+    The parameter tables and ``raw`` are the query's resident,
+    replicated stacks (row ``q * P_fam + key index``); ``qv`` holds each
+    cell's (perturbation, valid) and ``xyb`` its (x, y, backlog), both
+    sharded on ``chunks``."""
+    from repro.core import flitsim
+    sym_keys = [k for k in keys if k in flitsim.SYMMETRIC_PARAMS]
+    asym_keys = [k for k in keys if k in flitsim.ASYMMETRIC_PARAMS]
+    p_sym, p_asym = len(sym_keys), len(asym_keys)
+    col_src = [("sym", sym_keys.index(k)) if k in flitsim.SYMMETRIC_PARAMS
+               else ("asym", asym_keys.index(k)) for k in keys]
+    n_protocols = len(keys)
+    spec_c, spec_r = PartitionSpec("chunks"), PartitionSpec()
+
+    def cells_of(table, q, p_fam, *per_cell):
+        """Each cell's ``p_fam`` parameter rows and its per-cell values
+        repeated ``p_fam`` times, materialized once: without the barrier
+        XLA sinks the repeat into the scan and redoes it every cycle."""
+        rows = (q[:, None] * p_fam
+                + jnp.arange(p_fam, dtype=jnp.int32)).reshape(-1)
+        return jax.lax.optimization_barrier((
+            jax.tree_util.tree_map(lambda leaf: leaf[rows], table),
+            *(jnp.repeat(v, p_fam) for v in per_cell)))
+
+    def chunk_fn(sym_tab, asym_tab, raw_in, qv, xyb):
+        def body(sym_tab, asym_tab, raw_in, qv, xyb):
+            q, valid = qv[:, 0], qv[:, 1]
+            xs, ys, bs = xyb[:, 0], xyb[:, 1], xyb[:, 2]
+            eff_by = {}
+            if p_sym:
+                eff_by["sym"] = flitsim._symmetric_cells_grid(
+                    *cells_of(sym_tab, q, p_sym, xs, ys, bs),
+                    n_flits=n_flits).reshape(chunk, p_sym)
+            if p_asym:
+                eff_by["asym"] = flitsim._asymmetric_cells_grid(
+                    *cells_of(asym_tab, q, p_asym, xs, ys),
+                    n_accesses=n_accesses).reshape(chunk, p_asym)
+            eff = jnp.stack([eff_by[fam][:, i] for fam, i in col_src],
+                            axis=1)                         # [C, P]
+            m = eff[:, None, :] * raw_in[None, :, None]     # [C, F, P]
+            codes = jnp.argmax(m, axis=2).astype(jnp.int32)
+            ok = (valid > 0)[:, None, None]
+            onehot = codes[..., None] == jnp.arange(n_protocols,
+                                                    dtype=jnp.int32)
+            counts = jnp.sum((onehot & ok).astype(jnp.int32),
+                             axis=0)                        # [F, P]
+            best = jnp.max(jnp.where(ok, m, -jnp.inf),
+                           axis=(0, 1))                     # [P]
+            counts = jax.lax.psum(counts, "chunks")
+            best = jax.lax.pmax(best, "chunks")
+            return codes, counts, best
+
+        sharded = _shard_map(
+            body, mesh=mesh,
+            in_specs=(spec_r, spec_r, spec_r, spec_c, spec_c),
+            out_specs=(spec_c, spec_r, spec_r))
+        return sharded(sym_tab, asym_tab, raw_in, qv, xyb)
+
+    return chunk_fn
+
+
 def _stream_sim(space, metric: str, sim, stream) -> StreamResult:
     from repro.core import flitsim
     if sim.mode != "fixed":
@@ -301,70 +378,26 @@ def _stream_sim(space, metric: str, sim, stream) -> StreamResult:
         n_cells, stream, shape_perm)
 
     # perturbation-major parameter stacks (row = q * P_fam + key index —
-    # exactly simulate_grid's layout), gathered host-side per chunk
-    p_sym, p_asym = len(sym_keys), len(asym_keys)
-    sym_host = jax.tree_util.tree_map(np.asarray, flitsim.
-                                      SymmetricFlitParams.stack(
-                                          [flitsim.SYMMETRIC_PARAMS[k]
-                                           .perturbed(p)
-                                           for p in perts
-                                           for k in sym_keys]))
-    asym_host = jax.tree_util.tree_map(np.asarray, flitsim.
-                                       AsymmetricLaneParams.stack(
-                                           [flitsim.ASYMMETRIC_PARAMS[k]
-                                            .perturbed(p)
-                                            for p in perts
-                                            for k in asym_keys]))
-    col_src = [("sym", sym_keys.index(k)) if k in flitsim.SYMMETRIC_PARAMS
-               else ("asym", asym_keys.index(k)) for k in keys]
+    # exactly simulate_grid's layout), resident on the device for the
+    # whole query; each dispatch sends only its cells' indices
+    tables = (flitsim.SymmetricFlitParams.perturbed_stack(
+                  [flitsim.SYMMETRIC_PARAMS[k] for k in sym_keys], perts),
+              flitsim.AsymmetricLaneParams.perturbed_stack(
+                  [flitsim.ASYMMETRIC_PARAMS[k] for k in asym_keys], perts),
+              raw)
+    resident = jax.device_put(tables, NamedSharding(mesh, PartitionSpec()))
     n_protocols = len(keys)
     n_flits, n_accesses = int(space.n_flits), int(space.n_accesses)
-    spec_c, spec_r = PartitionSpec("chunks"), PartitionSpec()
+    chunk_fn = _sim_chunk_fn(mesh, keys, chunk, n_flits, n_accesses)
 
-    def chunk_fn(sym_cells, sxs, sys_, sbs, asym_cells, axs, ays, raw_in,
-                 valid):
-        def body(sym_cells, sxs, sys_, sbs, asym_cells, axs, ays, raw_in,
-                 valid):
-            eff_by = {}
-            if p_sym:
-                eff_by["sym"] = flitsim._symmetric_cells_grid(
-                    sym_cells, sxs, sys_, sbs,
-                    n_flits=n_flits).reshape(chunk, p_sym)
-            if p_asym:
-                eff_by["asym"] = flitsim._asymmetric_cells_grid(
-                    asym_cells, axs, ays,
-                    n_accesses=n_accesses).reshape(chunk, p_asym)
-            eff = jnp.stack([eff_by[fam][:, i] for fam, i in col_src],
-                            axis=1)                         # [C, P]
-            m = eff[:, None, :] * raw_in[None, :, None]     # [C, F, P]
-            codes = jnp.argmax(m, axis=2).astype(jnp.int32)
-            ok = (valid > 0)[:, None, None]
-            onehot = codes[..., None] == jnp.arange(n_protocols,
-                                                    dtype=jnp.int32)
-            counts = jnp.sum((onehot & ok).astype(jnp.int32),
-                             axis=0)                        # [F, P]
-            best = jnp.max(jnp.where(ok, m, -jnp.inf),
-                           axis=(0, 1))                     # [P]
-            counts = jax.lax.psum(counts, "chunks")
-            best = jax.lax.pmax(best, "chunks")
-            return codes, counts, best
-
-        sharded = _shard_map(
-            body, mesh=mesh,
-            in_specs=(spec_c, spec_c, spec_c, spec_c,
-                      spec_c, spec_c, spec_c, spec_r, spec_c),
-            out_specs=(spec_c, spec_r, spec_r))
-        return sharded(sym_cells, sxs, sys_, sbs, asym_cells, axs, ays,
-                       raw_in, valid)
-
-    key = ("sim", keys, chunk, devices, n_phys, n_flits, n_accesses,
-           sim.key())
+    # input shapes follow the perturbation count, never the backlog or
+    # mix axes' lengths (their values travel per cell)
+    key = ("sim", keys, len(perts), chunk, devices, n_phys, n_flits,
+           n_accesses, sim.key())
     misses0 = _stream_misses()
     codes_out = np.empty((n_cells, n_phys), np.int16)
     counts_total = np.zeros((n_phys, n_protocols), np.int64)
     best_total = np.full((n_protocols,), -np.inf, np.float64)
-    a_sym = np.arange(p_sym, dtype=np.int64)
-    a_asym = np.arange(p_asym, dtype=np.int64)
     prog = None
     prefetch = int(stream.prefetch)
     t0 = time.perf_counter()
@@ -393,22 +426,20 @@ def _stream_sim(space, metric: str, sim, stream) -> StreamResult:
             multi = np.unravel_index(ids, shape_perm)
             by_dim = {dims_all[order[j]]: multi[j]
                       for j in range(len(order))}
-            q_idx = by_dim["protocol_param"]
-            b_idx = by_dim["backlog"]
             if mix_dims:
                 m_idx = np.ravel_multi_index(
                     tuple(by_dim[d] for d in mix_dims), mix_shape)
             else:
                 m_idx = np.zeros(step, np.int64)
-            rows_sym = (q_idx[:, None] * p_sym + a_sym).reshape(-1)
-            rows_asym = (q_idx[:, None] * p_asym + a_asym).reshape(-1)
-            args = (
-                jax.tree_util.tree_map(lambda l: l[rows_sym], sym_host),
-                np.repeat(xf[m_idx], p_sym), np.repeat(yf[m_idx], p_sym),
-                np.repeat(backlogs[b_idx], p_sym),
-                jax.tree_util.tree_map(lambda l: l[rows_asym], asym_host),
-                np.repeat(xf[m_idx], p_asym), np.repeat(yf[m_idx], p_asym),
-                raw, valid)
+            qv = np.empty((step, 2), np.int32)      # (perturbation, valid)
+            xyb = np.empty((step, 3), np.float32)   # (x, y, backlog)
+            qv[:, 0] = by_dim["protocol_param"]
+            qv[:, 1] = valid
+            xyb[:, 0] = xf[m_idx]
+            xyb[:, 1] = yf[m_idx]
+            xyb[:, 2] = backlogs[by_dim["backlog"]]
+            host_args = (qv, xyb)
+            args = (*resident, *host_args)
             dm = time.perf_counter() - m0
         marshal_s += dm
         if inflight:                # marshalled while a chunk was in flight
@@ -427,7 +458,10 @@ def _stream_sim(space, metric: str, sim, stream) -> StreamResult:
         pad_cells=n_dispatch * step - n_cells,
         overlap_frac=overlap_s / marshal_s if marshal_s else 0.0,
         cells=n_cells, elapsed_s=time.perf_counter() - t0,
-        marshal_s=marshal_s)
+        marshal_s=marshal_s, dispatch_arrays=len(host_args),
+        dispatch_bytes=sum(a.nbytes for a in host_args),
+        resident_bytes=sum(leaf.nbytes for leaf in
+                           jax.tree_util.tree_leaves(tables)))
 
     pert_labels = (tuple(pert_ax.labels) if pert_ax is not None
                    else ("baseline",))

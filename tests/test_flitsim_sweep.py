@@ -5,6 +5,8 @@ scalar simulator outputs, and identically-shaped sweeps must reuse the warm
 compiled executable (no retrace).  No hypothesis dependency — these run
 everywhere the bare tier-1 environment does.
 """
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -176,3 +178,25 @@ class TestSweepAPI:
             [SymmetricFlitParams.cxl_unopt(), SymmetricFlitParams.chi()])
         assert stack.g_slots.shape == (2,)
         assert float(stack.g_slots[1]) == 12.0
+
+    @pytest.mark.parametrize("family", ["symmetric", "asymmetric"])
+    def test_perturbed_stack_equals_stacked_perturbed_params(self, family):
+        # the grids' and the stream's parameter stacks are built a field
+        # at a time; every value must equal the per-row perturbed-dataclass
+        # stack bit for bit
+        cls, reg = {
+            "symmetric": (SymmetricFlitParams, SYMMETRIC_PARAMS),
+            "asymmetric": (AsymmetricLaneParams, ASYMMETRIC_PARAMS)}[family]
+        perts = [{}, {"g_slots": 2.0}, {"g_slots": 1.0 / 3.0},
+                 {"credit_lines": 0.37, "total_lanes": 1.3},
+                 {"write_buffer_lines": 0.7, "slot_bits": 1.1111},
+                 {"read_lanes": 0.55, "cmd_bits_per_access": 0.9}]
+        bases = list(reg.values())
+        want = cls.stack([b.perturbed(p) for p in perts for b in bases])
+        got = cls.perturbed_stack(bases, perts)
+        for w, g in zip(dataclasses.astuple(want), dataclasses.astuple(got)):
+            w, g = np.asarray(w), np.asarray(g)
+            assert g.dtype == w.dtype == np.float32
+            assert g.shape == (len(perts) * len(bases),)
+            np.testing.assert_array_equal(g.view(np.int32),
+                                          w.view(np.int32))

@@ -213,6 +213,31 @@ class TestStreamingCompileCache:
         assert warm.compiles == 0
         assert cache_stats(STREAM_FAMILIES).misses == 1
 
+    def test_sim_program_shared_across_backlog_and_mix_counts(self):
+        # the chunk program's shapes follow the perturbation count alone:
+        # a smaller space over the same perturbations (as a warm-up
+        # streams) compiles the one program the full space then reuses
+        perts = axis("protocol_param", [{}, {"g_slots": 2.0},
+                                        {"g_slots": 3.0}])
+        stream = StreamConfig(chunk_cells=8, devices=1)
+
+        def space(backlogs, fracs):
+            return DesignSpace([
+                perts, axis("phy", [UCIE_S_32G, UCIE_A_32G_55U]),
+                axis("backlog", backlogs),
+                axis("read_fraction", np.linspace(0.0, 1.0, fracs)),
+            ], **FAST)
+
+        clear_cache(STREAM_FAMILIES)
+        small = space([4.0], 3).evaluate(metrics=("sim_bandwidth_gbs",),
+                                         stream=stream)
+        assert small.compiles == 1 and small.chunk_cells == 8
+        full = space([2.0, 16.0, 64.0], 7)
+        sr = full.evaluate(metrics=("sim_bandwidth_gbs",), stream=stream)
+        assert sr.compiles == 0 and sr.chunk_cells == 8
+        ref = full.evaluate(metrics=("sim_bandwidth_gbs",))
+        assert_same_winners(sr, ref["sim_bandwidth_gbs"].argbest("protocol"))
+
     def test_cache_stats_unknown_family_raises(self):
         with pytest.raises(KeyError, match="choose from"):
             cache_stats(("stream.bogus",))
@@ -265,6 +290,20 @@ class TestAsyncDispatch:
         assert 0.0 <= info["overlap_frac"] <= 1.0
         assert info["elapsed_s"] > 0.0
         assert 0.0 <= info["marshal_s"] <= info["elapsed_s"]
+
+    def test_stream_dispatch_counters(self):
+        # the parameter stacks and PHY bandwidths are placed once; each
+        # dispatch sends a [step, 2] int32 (perturbation, valid) and a
+        # [step, 3] float32 (x, y, backlog)
+        space = self._space()
+        sr = self._eval(space, chunk_cells=3, prefetch=2)
+        info = flitsim.last_run_info()["stream.sim"]
+        step = sr.chunk_cells * sr.devices
+        assert info["dispatch_arrays"] <= 2
+        assert info["dispatch_bytes"] == step * (2 * 4 + 3 * 4)
+        # 2 perturbations x (3 symmetric x 11 + 2 asymmetric x 6 fields)
+        # f32 rows, no PHY bandwidths for the plain efficiency
+        assert info["resident_bytes"] == 4 * (2 * (3 * 11 + 2 * 6) + 1)
 
     def test_single_chunk_smaller_than_space(self):
         # n_cells < chunk_cells: ONE dispatch; the drain loop (not the
@@ -438,6 +477,13 @@ class TestStreamingDistributed:
                               stream=StreamConfig(chunk_cells=7,
                                                   devices=8))
         assert warm.compiles == 0
+        one = space.evaluate(metrics=("sim_efficiency",),
+                             stream=StreamConfig(chunk_cells=7, devices=1))
+        np.testing.assert_array_equal(
+            np.asarray(sr.winners.values, dtype=object),
+            np.asarray(one.winners.values, dtype=object))
+        assert sr.win_counts == one.win_counts
+        assert sr.best_by_label == one.best_by_label
         print("OK 8-device sim streaming")
         """)
 
