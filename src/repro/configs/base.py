@@ -28,6 +28,20 @@ class ModelConfig:
     num_experts: int = 0
     experts_per_token: int = 0
     moe_capacity_factor: float = 1.25
+    moe_d_ff: int = 0              # routed-expert width (0 -> d_ff)
+    shared_experts: int = 0        # always-on experts of each MoE layer
+    first_k_dense: int = 0         # leading dense layers before the MoE ones
+
+    # multi-head latent attention (MLA; kv_lora_rank 0 -> absent): the
+    # cache holds a kv_lora_rank latent plus one shared rope key a token
+    q_lora_rank: int = 0           # 0 -> queries projected from d_model
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # multi-token prediction modules (not counted in param_count)
+    mtp_layers: int = 0
 
     # SSM (Mamba2 / SSD)
     ssm_state: int = 0
@@ -81,6 +95,14 @@ class ModelConfig:
     def is_moe(self) -> bool:
         return self.num_experts > 0
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def expert_d_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
     def layer_kinds(self) -> Tuple[str, ...]:
         """Per-layer block kind ('attn' | 'rec' | 'ssm' | 'moe')."""
         if self.family == "ssm":
@@ -89,15 +111,32 @@ class ModelConfig:
             pat = self.block_pattern
             return tuple(pat[i % len(pat)] for i in range(self.num_layers))
         if self.is_moe:
-            return ("moe",) * self.num_layers
+            return (("attn",) * self.first_k_dense
+                    + ("moe",) * (self.num_layers - self.first_k_dense))
         return ("attn",) * self.num_layers
 
     def homogeneous(self) -> bool:
         kinds = self.layer_kinds()
         return all(k == kinds[0] for k in kinds)
 
+    def mla_attn_params(self) -> int:
+        """Parameters of one latent-attention block: the query path
+        (through the ``q_lora_rank`` latent when set), the joint KV
+        down-projection with the shared rope key, the per-head KV
+        up-projection, the output projection and the two latent norms."""
+        d, h = self.d_model, self.num_heads
+        qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+        rank = self.q_lora_rank
+        q = (d * rank + rank + rank * h * qk) if rank else d * h * qk
+        kv = (d * (self.kv_lora_rank + self.qk_rope_head_dim)
+              + self.kv_lora_rank
+              + self.kv_lora_rank * h * (self.qk_nope_head_dim
+                                         + self.v_head_dim))
+        return q + kv + h * self.v_head_dim * d
+
     def param_count(self) -> int:
-        """Analytic parameter count (for MODEL_FLOPS = 6 N D)."""
+        """Analytic parameter count (for MODEL_FLOPS = 6 N D).  The
+        ``mtp_layers`` modules are left out, as published counts do."""
         d, f, v = self.d_model, self.d_ff, self.vocab_size
         emb = v * d * (1 if self.tie_embeddings else 2)
         per_layer = 0
@@ -105,13 +144,15 @@ class ModelConfig:
         for kind in self.layer_kinds():
             counts[kind] += 1
         h, k, hd = self.num_heads, self.num_kv_heads, self.head_dim
-        attn_p = d * (h + 2 * k) * hd + h * hd * d
+        attn_p = (self.mla_attn_params() if self.is_mla
+                  else d * (h + 2 * k) * hd + h * hd * d)
         mlp_p = d * f * (3 if self.mlp_gated else 2)
         counts_total = 0
         counts_total += counts["attn"] * (attn_p + mlp_p + 2 * d)
         if counts["moe"]:
             e = self.num_experts
-            moe_mlp = e * d * f * (3 if self.mlp_gated else 2) + d * e
+            expert_p = d * self.expert_d_ff * (3 if self.mlp_gated else 2)
+            moe_mlp = (e + self.shared_experts) * expert_p + d * e
             counts_total += counts["moe"] * (attn_p + moe_mlp + 2 * d)
         if counts["rec"]:
             lru = d  # lru width == d_model
@@ -135,7 +176,7 @@ class ModelConfig:
         if not self.is_moe:
             return self.param_count()
         full = self.param_count()
-        d, f, e = self.d_model, self.d_ff, self.num_experts
+        d, f, e = self.d_model, self.expert_d_ff, self.num_experts
         moe_layers = sum(1 for kk in self.layer_kinds() if kk == "moe")
         expert_p = d * f * (3 if self.mlp_gated else 2)
         inactive = moe_layers * (e - self.experts_per_token) * expert_p

@@ -19,6 +19,14 @@ _ARCH_MODULES = {
     "internvl2-1b": "repro.configs.internvl2_1b",
 }
 
+#: configurations priced for serving traffic only
+#: (``repro.traces.model_traffic``): ``repro.models`` builds none of
+#: them, so they stay out of ``arch_ids()`` and the arch-parametrized
+#: tests
+_TRAFFIC_MODULES = {
+    "deepseek-v3": "repro.configs.deepseek_v3",
+}
+
 
 def arch_ids() -> List[str]:
     return list(_ARCH_MODULES)
@@ -29,6 +37,13 @@ def get(arch_id: str) -> ModelConfig:
         raise KeyError(f"unknown arch {arch_id!r}; known: {arch_ids()}")
     mod = importlib.import_module(_ARCH_MODULES[arch_id])
     return mod.CONFIG
+
+
+def traffic_config(arch_id: str) -> ModelConfig:
+    """A registered architecture or a traffic-only configuration."""
+    if arch_id in _TRAFFIC_MODULES:
+        return importlib.import_module(_TRAFFIC_MODULES[arch_id]).CONFIG
+    return get(arch_id)
 
 
 def all_configs() -> Dict[str, ModelConfig]:

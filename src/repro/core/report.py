@@ -23,11 +23,20 @@ Sections:
 * ``"phy"`` — the PHY-stacked analytic frontier (UCIe-A/S, 32G + 48G).
 * ``"sim_phy"`` — its cycle-level counterpart (simulated efficiency x
   raw PHY bandwidth, per queue depth).
-* ``"serving"`` — the per-(model, QPS) serving-trace winner map.
+* ``"serving"`` — the per-(model, QPS) serving-trace winner map, or
+  one :class:`~repro.traces.deployment.ServingDeployment`'s (option
+  ``deployment``).
+* ``"workloads"`` — the workload->design-space bridge
+  (:func:`repro.roofline.analysis.bridge_design_space`) over the
+  roofline reports of option ``reports``.
 
 Every section accepts keyword options via ``ReportSpec.options`` (keyed
 by section name); ``verbose=True`` reproduces the explorer's progress
-prints byte-for-byte (the explorer wrappers pass it).
+prints byte-for-byte (the explorer wrappers pass it).  Each section runs
+inside a ``repro.report.section`` span (family: the section), and its
+seconds and engine runs (sequential cycles, cells, probe-certified
+cells) are the ``"report"`` counters of
+:func:`repro.core.flitsim.last_run_info`.
 """
 from __future__ import annotations
 
@@ -36,6 +45,7 @@ import time
 from typing import Any, Dict, Mapping, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = ["FrontierReport", "ReportSpec", "build_report"]
 
@@ -91,15 +101,19 @@ def build_report(spec: Optional[ReportSpec] = None, *,
     ``"frontier"`` section reduces (required for that section only;
     :meth:`DesignSpace.report` passes itself).
     """
+    from repro.core import flitsim
     spec = spec if spec is not None else ReportSpec()
     builders = {"frontier": _frontier_section, "joint": _joint_section,
                 "phy": _phy_section, "sim_phy": _sim_phy_section,
-                "serving": _serving_section}
+                "serving": _serving_section,
+                "workloads": _workloads_section}
     unknown = [s for s in spec.sections if s not in builders]
     if unknown:
         raise ValueError(f"unknown report sections {unknown}; choose "
                          f"from {sorted(builders)}")
     out: Dict[str, FrontierReport] = {}
+    seconds: Dict[str, float] = {}
+    engines: Dict[str, Dict[str, int]] = {}
     for section in spec.sections:
         if section == "frontier" and space is None:
             raise ValueError(
@@ -110,9 +124,33 @@ def build_report(spec: Optional[ReportSpec] = None, *,
         if section in ("joint", "sim_phy", "frontier") \
                 and spec.sim is not None:
             opts.setdefault("sim", spec.sim)
-        payload = builders[section](space, spec.verbose, **opts)
+        mark = flitsim.run_mark()
+        t0 = time.perf_counter()
+        with TraceAnnotation("repro.report.section", family=section):
+            payload = builders[section](space, spec.verbose, **opts)
+        seconds[section] = time.perf_counter() - t0
+        engines[section] = _engine_counts(flitsim.runs_since(mark))
         out[section] = FrontierReport(section=section, payload=payload)
+    flitsim.record_counters("report", seconds=seconds, engines=engines)
     return out
+
+
+def _engine_counts(runs: Mapping[str, Mapping[str, Any]]) -> Dict[str, int]:
+    """What a section's engine runs did (each section evaluates one space,
+    which runs each engine family once): sequential cycles (an adaptive
+    run's ``sequential_depth``, a trace scan's ``cycles_run``), the
+    adaptive runs' cells and those of them the periodic probes
+    certified."""
+    depth = cells = certified = 0
+    for rec in runs.values():
+        if rec.get("mode") == "adaptive":
+            depth += rec["sequential_depth"]
+            cells += rec["cells"]
+            certified += sum(rec.get("periods", {}).values())
+        elif rec.get("mode") == "trace":
+            depth += rec["cycles_run"]
+    return {"sequential_depth": depth, "cells": cells,
+            "certified_cells": certified}
 
 
 # =========================================================================
@@ -310,6 +348,15 @@ def _sim_phy_section(space, verbose, *, n_fracs: int = 21,
     report["shallow_queue_disagrees"] = {
         name: shallow[name] != deep_w[name] for name in shallow}
     return report
+
+
+def _workloads_section(space, verbose, *, reports, **opts
+                       ) -> Dict[str, Any]:
+    """The workload->design-space bridge over ``reports`` (name ->
+    :class:`repro.roofline.analysis.RooflineReport`); ``opts`` as for
+    :func:`repro.roofline.analysis.bridge_design_space`."""
+    from repro.roofline.analysis import bridge_design_space
+    return bridge_design_space(reports, **opts)
 
 
 def _serving_section(space, verbose, *, models=None, qps_points=None,

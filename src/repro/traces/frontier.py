@@ -13,13 +13,22 @@ QPS sensitivity is the point: low-QPS traces sit at drained backlogs and
 decode-heavy read fractions, high-QPS traces saturate the queue and mix
 in prefill write bursts, so the winning approach can flip along the QPS
 axis — a frontier the static-mix sections cannot express.
+
+Given a :class:`~repro.traces.deployment.ServingDeployment`, the section
+answers for that one deployment instead: its model, every arrival
+process of ``arrivals``, and QPS points given as multiples of the
+deployment's service rate; the payload records the deployment, the
+per-trace bandwidths and per-phase efficiencies behind each winner.
 """
 from __future__ import annotations
 
+import time
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.traces.deployment import ServingDeployment
 from repro.traces.model_traffic import ModelTrafficSpec
 from repro.traces.synthetic import synthetic_serving_trace
 
@@ -41,34 +50,67 @@ def serving_frontier(models: Sequence[str] = DEFAULT_MODELS,
                      protocols: Optional[Sequence[str]] = None,
                      n_phases: int = 6, n_ticks: int = 384,
                      batch_slots: int = 32, arrival: str = "diurnal",
-                     seed: int = 0, sim=None) -> Dict[str, Any]:
+                     seed: int = 0, sim=None,
+                     deployment: Optional[ServingDeployment] = None,
+                     arrivals: Optional[Sequence[str]] = None
+                     ) -> Dict[str, Any]:
     """Build the per-(model, QPS) serving-frontier report.
 
     ``phy`` defaults to the paper's UCIe-A 32G point; ``sim`` is the
     trace engine's :class:`~repro.core.space.SimConfig` (fixed trace-scan
     core by default).  Winner labels are catalog approach keys
     (``A:lpddr6-asym`` ...), the vocabulary the summary golden gates.
+
+    With ``deployment``, ``models`` is ignored, ``qps_points`` are
+    multiples of the deployment's service rate, and every process of
+    ``arrivals`` (default ``[arrival]``) is replayed at each of them.
     """
     from repro.core import UCIE_A_32G_55U, flitsim
-    from repro.core.selector import approach_key_for
     from repro.core.space import DesignSpace, axis
 
     if phy is None:
         phy = UCIE_A_32G_55U
-    traces = [
-        synthetic_serving_trace(
-            ModelTrafficSpec.from_name(m), qps=q, n_ticks=n_ticks,
-            n_phases=n_phases, batch_slots=batch_slots, arrival=arrival,
-            seed=seed, name=f"{m}@q{q:g}")
-        for m in models for q in qps_points]
+    t0 = time.perf_counter()
+    replay: Dict[str, Any] = {}
+    if deployment is None:
+        points = [(m, q, arrival) for m in models for q in qps_points]
+        names = [f"{m}@q{q:g}" for m, q, _ in points]
+        specs = {m: ModelTrafficSpec.from_name(m) for m in models}
+    else:
+        models = [deployment.model]
+        mu = deployment.service_rate()
+        arrivals = list(arrivals) if arrivals is not None else [arrival]
+        points = [(deployment.model, x * mu, a) for a in arrivals
+                  for x in qps_points]
+        names = [f"{deployment.model}@{a}x{x:g}" for a in arrivals
+                 for x in qps_points]
+        specs = {deployment.model: deployment.spec()}
+    traces = []
+    for i, ((m, q, arr), name) in enumerate(zip(points, names)):
+        with TraceAnnotation("repro.traces.replay", index=i):
+            counts: Dict[str, Any] = {}
+            traces.append(synthetic_serving_trace(
+                specs[m], qps=q, n_ticks=n_ticks, n_phases=n_phases,
+                batch_slots=batch_slots, arrival=arr, seed=seed, name=name,
+                deployment=deployment, counters=counts))
+            for key, v in counts.items():
+                replay[key] = replay.get(key, 0) + v
+    flitsim.record_counters(
+        "traces.replay", traces=len(traces), ticks=len(traces) * n_ticks,
+        replay_s=time.perf_counter() - t0, **_replay_shares(replay))
 
     before = flitsim.compile_cache_stats()
     axes = [axis("trace", traces)]
     if protocols is not None:
         axes.append(axis("protocol", protocols))
     res = DesignSpace(axes, phy=phy, sim=sim).evaluate(
-        metrics=("trace_efficiency", "trace_bandwidth_gbs"))
+        metrics=("trace_phase_efficiency", "trace_bandwidth_gbs"))
     after = flitsim.compile_cache_stats()
+    if deployment is not None:
+        return _deployment_payload(deployment, arrivals, qps_points, phy,
+                                   n_ticks, traces, res,
+                                   after.misses - before.misses)
+    from repro.core.selector import approach_key_for
 
     bw = res["trace_bandwidth_gbs"]             # [protocol, trace]
     best = bw.argbest("protocol")               # [trace]
@@ -111,4 +153,71 @@ def serving_frontier(models: Sequence[str] = DEFAULT_MODELS,
             for t in traces},
         "telemetry": tele,
         "compiles": after.misses - before.misses,
+    }
+
+
+def _replay_shares(replay: Dict[str, Any]) -> Dict[str, Any]:
+    """The session replays' counts as the ``traces.replay`` counters:
+    prefill chunks, the share of admitted asks that hit a resident
+    prompt, and the mean expert union of the ticks that did work."""
+    if not replay:
+        return {}
+    asks, busy = replay["asks_admitted"], replay["busy_ticks"]
+    return {"prefill_chunks": replay["prefill_chunks"],
+            "prefix_hit_share": (replay["prefix_hits"] / asks
+                                 if asks else 0.0),
+            "expert_union_mean": (replay["expert_union_sum"] / busy
+                                  if busy else 0.0)}
+
+
+def _deployment_payload(dep: ServingDeployment, arrivals, multiples, phy,
+                        n_ticks: int, traces, res,
+                        compiles: int) -> Dict[str, Any]:
+    """The serving section of one deployment: winners per trace (arrival
+    and multiple of the service rate), with the bandwidths and per-phase
+    efficiencies behind them and the deployment it answered."""
+    from repro.core import flitsim
+    from repro.core.selector import approach_key_for
+    bw = res["trace_bandwidth_gbs"]             # [protocol, trace]
+    eff = res["trace_phase_efficiency"]         # [protocol, trace, phase]
+    best = bw.argbest("protocol")
+    best_gbs = bw.best("protocol")
+    names = [t.name for t in traces]
+    protocols = list(bw.coord("protocol"))
+    model = dep.model
+    keys = [f"{a}@{x:g}" for a in arrivals for x in multiples]
+    proto = {k: str(best.values[i]) for i, k in enumerate(keys)}
+    winner = {k: approach_key_for(p) for k, p in proto.items()}
+    return {
+        "deployment": dep.record(),
+        "models": [model],
+        "arrival": list(arrivals),
+        "qps_multiples": [float(x) for x in multiples],
+        "qps_points": [float(x) * dep.service_rate() for x in multiples],
+        "phy": phy.name,
+        "n_ticks": int(n_ticks),
+        "n_phases": int(max(t.n_phases for t in traces)),
+        "protocols": protocols,
+        "trace_names": names,
+        "winner_by_model_qps": {model: winner},
+        "protocol_by_model_qps": {model: proto},
+        "winner_gbs_by_model_qps": {model: {
+            k: float(best_gbs.values[i]) for i, k in enumerate(keys)}},
+        "qps_sensitive": {model: len(set(winner.values())) > 1},
+        "trace_bandwidth_gbs": {
+            p: np.asarray(bw.values[j], np.float64).tolist()
+            for j, p in enumerate(protocols)},
+        "phase_efficiency": {
+            p: np.asarray(eff.values[j], np.float64).tolist()
+            for j, p in enumerate(protocols)},
+        "traces": {
+            t.name: {"durations": list(t.durations),
+                     "read_fractions": list(t.read_fractions),
+                     "backlogs": list(t.backlogs)}
+            for t in traces},
+        "replay": flitsim.last_run_info()["traces.replay"],
+        "telemetry": {fam: info for fam, info
+                      in flitsim.last_run_info().items()
+                      if info.get("mode") == "trace"},
+        "compiles": compiles,
     }
