@@ -81,13 +81,10 @@ def bursty_arrivals(base_rate: float, n_ticks: int,
                          f"{mean_burst_len}")
     rng = np.random.default_rng(seed)
     n = int(n_ticks)
-    rates = np.empty(n, np.float64)
-    in_burst = False
-    for t in range(n):
-        if in_burst:
-            if rng.random() < 1.0 / mean_burst_len:
-                in_burst = False
-        elif rng.random() < burst_prob:
-            in_burst = True
-        rates[t] = base_rate * (burst_factor if in_burst else 1.0)
+    # one uniform a tick decides the switch, drawn up front
+    bursts, in_burst = [], False
+    for u in rng.random(n).tolist():
+        in_burst = u >= 1.0 / mean_burst_len if in_burst else u < burst_prob
+        bursts.append(in_burst)
+    rates = base_rate * np.where(np.asarray(bursts, bool), burst_factor, 1.0)
     return rng.poisson(rates).astype(np.int64)

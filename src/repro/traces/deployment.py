@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, List, Tuple
 
 import numpy as np
 
@@ -41,6 +41,18 @@ class LengthDist:
             x = rng.lognormal(mu, self.sigma)
             if self.lo <= x <= self.hi:
                 return int(x + 0.5)
+
+    def draws(self, rng: np.random.Generator, n: int) -> List[int]:
+        """``n`` lengths, from the same lognormal draws of ``rng`` as
+        ``n`` calls of :meth:`draw` (a round never draws past the last
+        length it needs)."""
+        mu = math.log(self.median)
+        out: List[int] = []
+        while len(out) < n:
+            for x in rng.lognormal(mu, self.sigma, n - len(out)).tolist():
+                if self.lo <= x <= self.hi:
+                    out.append(int(x + 0.5))
+        return out
 
     def survival(self, t: float) -> float:
         """``P(X >= t)`` of the truncated (unrounded) length."""

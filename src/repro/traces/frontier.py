@@ -30,7 +30,9 @@ from jax.profiler import TraceAnnotation
 
 from repro.traces.deployment import ServingDeployment
 from repro.traces.model_traffic import ModelTrafficSpec
-from repro.traces.synthetic import synthetic_serving_trace
+from repro.traces.synthetic import (replay_sessions_batch,
+                                    synthetic_serving_trace)
+from repro.traces.trace import TrafficTrace
 
 #: model configs the committed artifact sweeps: a dense decoder, a MoE
 #: (expert-shuffle bytes), and an SSM (context-independent state reads)
@@ -73,28 +75,33 @@ def serving_frontier(models: Sequence[str] = DEFAULT_MODELS,
     t0 = time.perf_counter()
     replay: Dict[str, Any] = {}
     if deployment is None:
-        points = [(m, q, arrival) for m in models for q in qps_points]
-        names = [f"{m}@q{q:g}" for m, q, _ in points]
         specs = {m: ModelTrafficSpec.from_name(m) for m in models}
+        traces = []
+        for i, (m, q) in enumerate((m, q) for m in models
+                                   for q in qps_points):
+            with TraceAnnotation("repro.traces.replay", index=i):
+                traces.append(synthetic_serving_trace(
+                    specs[m], qps=q, n_ticks=n_ticks, n_phases=n_phases,
+                    batch_slots=batch_slots, arrival=arrival, seed=seed,
+                    name=f"{m}@q{q:g}"))
     else:
         models = [deployment.model]
         mu = deployment.service_rate()
         arrivals = list(arrivals) if arrivals is not None else [arrival]
-        points = [(deployment.model, x * mu, a) for a in arrivals
-                  for x in qps_points]
+        points = [(x * mu, a) for a in arrivals for x in qps_points]
         names = [f"{deployment.model}@{a}x{x:g}" for a in arrivals
                  for x in qps_points]
-        specs = {deployment.model: deployment.spec()}
-    traces = []
-    for i, ((m, q, arr), name) in enumerate(zip(points, names)):
-        with TraceAnnotation("repro.traces.replay", index=i):
-            counts: Dict[str, Any] = {}
-            traces.append(synthetic_serving_trace(
-                specs[m], qps=q, n_ticks=n_ticks, n_phases=n_phases,
-                batch_slots=batch_slots, arrival=arr, seed=seed, name=name,
-                deployment=deployment, counters=counts))
-            for key, v in counts.items():
+        with TraceAnnotation("repro.traces.replay", index=0):
+            reps, capacity = replay_sessions_batch(
+                deployment.spec(), deployment, points, n_ticks=n_ticks,
+                seed=seed)
+            traces = [TrafficTrace.from_ticks(
+                name, r.read_bytes, r.write_bytes, r.backlog,
+                n_phases=n_phases) for name, r in zip(names, reps)]
+        for r in reps:
+            for key, v in r.counters().items():
                 replay[key] = replay.get(key, 0) + v
+        replay.update(device_traces=len(reps), session_capacity=capacity)
     flitsim.record_counters(
         "traces.replay", traces=len(traces), ticks=len(traces) * n_ticks,
         replay_s=time.perf_counter() - t0, **_replay_shares(replay))
@@ -159,7 +166,8 @@ def serving_frontier(models: Sequence[str] = DEFAULT_MODELS,
 def _replay_shares(replay: Dict[str, Any]) -> Dict[str, Any]:
     """The session replays' counts as the ``traces.replay`` counters:
     prefill chunks, the share of admitted asks that hit a resident
-    prompt, and the mean expert union of the ticks that did work."""
+    prompt, the mean expert union of the ticks that did work, the traces
+    the device program replayed and its padded session axis."""
     if not replay:
         return {}
     asks, busy = replay["asks_admitted"], replay["busy_ticks"]
@@ -167,7 +175,9 @@ def _replay_shares(replay: Dict[str, Any]) -> Dict[str, Any]:
             "prefix_hit_share": (replay["prefix_hits"] / asks
                                  if asks else 0.0),
             "expert_union_mean": (replay["expert_union_sum"] / busy
-                                  if busy else 0.0)}
+                                  if busy else 0.0),
+            "device_traces": replay["device_traces"],
+            "session_capacity": replay["session_capacity"]}
 
 
 def _deployment_payload(dep: ServingDeployment, arrivals, multiples, phy,

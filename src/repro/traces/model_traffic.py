@@ -45,6 +45,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Tuple
 
+import numpy as np
+
 
 @dataclasses.dataclass(frozen=True)
 class ModelTrafficSpec:
@@ -181,12 +183,12 @@ class ModelTrafficSpec:
                             ) -> Tuple[float, float]:
         """One chunk of ``tokens`` prompt tokens at ``offset``: write
         their cache entries, read the cached prefix and the chunk back
-        once (flash-style), and shuffle the chunk through the
-        experts."""
-        n = max(int(tokens), 0)
+        once (flash-style), and shuffle the chunk through the experts.
+        ``offset`` and ``tokens`` may be integer arrays of chunks."""
+        n = np.maximum(tokens, 0)
         per_token = (self.state_bytes_per_token / 2.0
                      + self.moe_shuffle_bytes_per_token / 2.0)
-        reads = ((max(int(offset), 0) + n) * self.kv_write_bytes_per_token
+        reads = ((np.maximum(offset, 0) + n) * self.kv_write_bytes_per_token
                  + n * per_token)
         writes = n * (self.kv_write_bytes_per_token + per_token)
         return reads, writes

@@ -129,16 +129,25 @@ class TrafficTrace:
         if tot_r + tot_w <= 0.0:
             raise ValueError(f"trace {name!r}: no bytes recorded")
         global_rf = tot_r / (tot_r + tot_w)
-        durs, rfs, bls = [], [], []
-        for rs, ws, bs in zip(np.array_split(r, n_phases),
-                              np.array_split(w, n_phases),
-                              np.array_split(b, n_phases)):
-            seg = float(rs.sum() + ws.sum())
-            durs.append(float(rs.size))
-            rfs.append(float(rs.sum()) / seg if seg > 0.0 else global_rf)
-            bls.append(max(float(bs.mean()), MIN_BACKLOG))
-        return cls(name=name, durations=tuple(durs),
-                   read_fractions=tuple(rfs), backlogs=tuple(bls))
+        # the slices of np.array_split (the first ``longer`` one tick
+        # longer), each summed on its own as a contiguous row
+        size, longer = divmod(r.size, n_phases)
+        cut = longer * (size + 1)
+
+        def sums(x):
+            return np.concatenate([
+                x[:cut].reshape(longer, size + 1).sum(axis=1),
+                x[cut:].reshape(n_phases - longer, size).sum(axis=1)])
+
+        rs = sums(r)
+        seg = rs + sums(w)
+        durs = np.where(np.arange(n_phases) < longer, size + 1.0, size)
+        rfs = np.where(seg > 0.0, rs / np.where(seg > 0.0, seg, 1.0),
+                       global_rf)
+        bls = np.maximum(sums(b) / durs, MIN_BACKLOG)
+        return cls(name=name, durations=tuple(durs.tolist()),
+                   read_fractions=tuple(rfs.tolist()),
+                   backlogs=tuple(bls.tolist()))
 
 
 def pad_traces(traces: Sequence[TrafficTrace]) -> Tuple[TrafficTrace, ...]:

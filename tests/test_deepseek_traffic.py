@@ -95,11 +95,92 @@ def test_prefill_chunk_reads_the_prefix():
     assert r1 - r0 == pytest.approx(400 * spec.kv_write_bytes_per_token)
 
 
+def _loop_replay(spec, dep, *, qps, n_ticks, arrival, seed, resident):
+    """The session replay as a plain loop over ticks (the program's
+    reference): per-tick reads, writes, backlog, and hits, misses and
+    prefill chunks; ``resident=False`` prefills every repeat again."""
+    from collections import deque
+    from repro.traces.synthetic import ARRIVALS
+    starts = ARRIVALS[arrival](qps / dep.mean_asks, n_ticks, seed=seed)
+    rng = np.random.default_rng(seed + 1)
+    sessions, due, queue = [], {}, deque()
+    sess, ctx, left = [-1] * dep.batch_slots, [0] * dep.batch_slots, \
+        [0] * dep.batch_slots
+    prefilling, hits, misses, chunks = [], 0, 0, 0
+    kv = spec.kv_write_bytes_per_token
+    token = (spec.state_bytes_per_token / 2.0
+             + spec.moe_shuffle_bytes_per_token / 2.0)
+    reads_t, writes_t, backlog = [], [], []
+    for t in range(n_ticks):
+        queue.extend(sorted(due.pop(t, ())))
+        for _ in range(int(starts[t])):
+            prompt = dep.prompt.draw(rng)
+            k = int(rng.integers(dep.asks_per_prompt[0],
+                                 dep.asks_per_prompt[1] + 1))
+            answers = [dep.answer.draw(rng) for _ in range(k)]
+            gaps = [float(rng.exponential(dep.ask_gap_ticks))
+                    for _ in range(k - 1)]
+            queue.append(len(sessions))
+            sessions.append([prompt, answers, gaps, 0])
+        for i in range(dep.batch_slots):
+            if queue and sess[i] < 0:
+                s = queue.popleft()
+                sess[i], left[i] = s, sessions[s][1][sessions[s][3]]
+                if resident and sessions[s][3] > 0:
+                    ctx[i], hits = sessions[s][0], hits + 1
+                else:
+                    ctx[i], misses = 0, misses + 1
+                    prefilling.append(i)
+        reads = writes = 0.0
+        tokens, budget = 0, dep.chunk_tokens
+        while prefilling and budget:
+            i = prefilling[0]
+            c = min(sessions[sess[i]][0] - ctx[i], budget)
+            r, w = spec.prefill_chunk_bytes(ctx[i], c)
+            reads, writes = reads + r, writes + w
+            ctx[i], budget, tokens, chunks = (ctx[i] + c, budget - c,
+                                              tokens + c, chunks + 1)
+            if ctx[i] == sessions[sess[i]][0]:
+                prefilling.pop(0)
+        held = sum(s >= 0 for s in sess)
+        decoding = [i for i in range(dep.batch_slots)
+                    if sess[i] >= 0 and i not in prefilling]
+        for i in decoding:
+            reads += ctx[i] * kv + token
+            writes += kv + token
+            ctx[i], left[i] = ctx[i] + 1, left[i] - 1
+            if left[i] == 0:
+                rec = sessions[sess[i]]
+                rec[3] += 1
+                if rec[3] < len(rec[1]):
+                    due.setdefault(t + 1 + int(rec[2][rec[3] - 1]),
+                                   []).append(sess[i])
+                sess[i] = -1
+        tokens += len(decoding)
+        if tokens:
+            reads += spec.tick_weight_bytes(tokens)
+        reads_t.append(reads)
+        writes_t.append(writes)
+        backlog.append(float(len(queue) + held))
+    return (np.asarray(reads_t), np.asarray(writes_t), np.asarray(backlog),
+            hits, misses, chunks)
+
+
 def test_replay_hits_skip_prefill_and_chunks_keep_the_budget(monkeypatch):
     from repro.traces import synthetic
     spec = SMALL.spec()
-    rep = replay_sessions(spec, SMALL, qps=SMALL.service_rate(),
-                          n_ticks=256, arrival="poisson", seed=11)
+    load = dict(qps=SMALL.service_rate(), n_ticks=256, arrival="poisson",
+                seed=11)
+
+    def same_as_the_loop(got, resident):
+        want = _loop_replay(spec, SMALL, resident=resident, **load)
+        np.testing.assert_array_equal(got.read_bytes, want[0])
+        np.testing.assert_array_equal(got.write_bytes, want[1])
+        np.testing.assert_array_equal(got.backlog, want[2])
+        assert (got.hits, got.misses, got.prefill_chunks) == want[3:]
+
+    rep = replay_sessions(spec, SMALL, **load)
+    same_as_the_loop(rep, resident=True)
     c = rep.counters()
     assert c["prefix_hits"] > 0 and c["asks_admitted"] > c["prefix_hits"]
     # prompts of 300-2400 tokens in chunks of at most 512
@@ -107,14 +188,80 @@ def test_replay_hits_skip_prefill_and_chunks_keep_the_budget(monkeypatch):
     # the same sessions with every repeat prefilled again write more
     monkeypatch.setattr(synthetic._Session, "resident",
                         property(lambda self: False))
-    cold = replay_sessions(spec, SMALL, qps=SMALL.service_rate(),
-                           n_ticks=256, arrival="poisson", seed=11)
+    cold = replay_sessions(spec, SMALL, **load)
+    same_as_the_loop(cold, resident=False)
     assert cold.counters()["prefix_hits"] == 0
     assert cold.write_bytes.sum() > rep.write_bytes.sum()
     # a tick's cache writes never exceed the chunk budget plus a token a
     # slot; the kv part of the writes bounds it
     kv = spec.kv_write_bytes_per_token + spec.moe_shuffle_bytes_per_token / 2
     assert rep.write_bytes.max() <= (512 + 4) * kv * (1 + 1e-12)
+
+
+#: the deployment of the benchmark's ``bridge.report`` cell
+BRIDGE = ServingDeployment(DSV3)
+
+#: sha256 of the session replay's per-tick float64 reads, writes and
+#: backlog and its counters at 0.25, 1 and 2 times the service rate over
+#: 512 ticks, pinned from the per-tick Python loop the device program
+#: replaced
+PINNED_REPLAY = {
+    ("bridge", "poisson", 11):
+        "9284244fbad94c9f6e54a05bd6c7f3ed1cc86d286bdba2046105e865e52b832b",
+    ("bridge", "poisson", 2 ** 31 + 3):
+        "c4181adcb2e7b77456262a683e09d906ad3747949ce58e9c5ec03bcfd8c129bc",
+    ("bridge", "bursty", 11):
+        "3a55f2de77f3d20b5ae284a3a7d40f662c133a13413b7983f4289d532ca4b2eb",
+    ("bridge", "bursty", 2 ** 31 + 3):
+        "6da7559c2c6660794e14ca6d6224ada88efe795908080bff42bf3fedcd49c06a",
+    ("small", "poisson", 11):
+        "ea2fbff047abaf160a20390489325a2f971696d6a5ae9e2631f305f4a76c52dc",
+    ("small", "poisson", 2 ** 31 + 3):
+        "fb51f3c4b31698c0c6bed91efff84ba8237e17c90b0746d3463b0ec0460650f3",
+    ("small", "bursty", 11):
+        "514cc516722190262085e10103c41b50454ad645b895dca41b4b72d54ec09f31",
+    ("small", "bursty", 2 ** 31 + 3):
+        "1ea317ed38eabf729be6714b9712928f8767ee14414ef80ddd2bbad666ac1052",
+}
+
+
+@pytest.mark.parametrize("case", sorted(PINNED_REPLAY))
+def test_replay_bit_for_bit_as_pinned(case):
+    from repro.traces.synthetic import replay_sessions_batch
+    name, arrival, seed = case
+    dep = {"bridge": BRIDGE, "small": SMALL}[name]
+    reps, capacity = replay_sessions_batch(
+        dep.spec(), dep, [(x * dep.service_rate(), arrival)
+                          for x in (0.25, 1.0, 2.0)], n_ticks=512, seed=seed)
+    h = hashlib.sha256()
+    for rep in reps:
+        for a in (rep.read_bytes, rep.write_bytes, rep.backlog):
+            h.update(np.ascontiguousarray(a, np.float64).tobytes())
+        h.update(repr((rep.prefill_chunks, rep.hits, rep.misses,
+                       rep.busy_ticks, rep.union_sum)).encode())
+    assert h.hexdigest() == PINNED_REPLAY[case]
+    assert capacity & (capacity - 1) == 0
+
+
+def test_serving_frontier_replays_in_one_program():
+    """Every trace of a deployment's section replays in one program run;
+    a seed whose sessions fit the same padded axis compiles nothing."""
+    from repro.core import flitsim, space
+    from repro.traces import serving_frontier
+    space.clear_cache(["traces.replay"])
+    seen = []
+    for seed in (5, 6):
+        serving_frontier(deployment=SMALL, arrivals=["poisson", "bursty"],
+                         qps_points=[0.5, 2.0], n_ticks=256, n_phases=2,
+                         protocols=["hbm_asym"], seed=seed)
+        info = flitsim.last_run_info()["traces.replay"]
+        assert info["traces"] == info["device_traces"] == 4
+        seen.append((info["session_capacity"],
+                     space.cache_stats(["traces.replay"])))
+    (cap5, first), (cap6, second) = seen
+    assert cap5 == cap6
+    assert (first.misses, first.hits) == (1, 0)
+    assert (second.misses, second.hits) == (1, 1)
 
 
 def test_lengths_are_heavy_tailed_and_truncated():
@@ -126,6 +273,16 @@ def test_lengths_are_heavy_tailed_and_truncated():
                                                     abs=0.03)
     assert draws.mean() > np.median(draws)
     assert dist.mean() == pytest.approx(draws.mean(), rel=0.03)
+
+
+def test_bulk_draws_take_the_same_stream():
+    """``draws(rng, n)`` is ``n`` calls of ``draw``, rejections included,
+    and leaves the generator where they leave it."""
+    dist = ServingDeployment(DSV3).prompt          # ~13% drawn below lo
+    a, b = np.random.default_rng(9), np.random.default_rng(9)
+    for n in (1, 4, 50):
+        assert dist.draws(a, n) == [dist.draw(b) for _ in range(n)]
+    assert a.random() == b.random()
 
 
 def test_service_rate():
@@ -161,6 +318,7 @@ def test_serving_section_records_its_deployment(monkeypatch):
     assert eff.shape == (2, 4)
     info = flitsim.last_run_info()
     assert info["traces.replay"]["traces"] == 2
+    assert info["traces.replay"]["device_traces"] == 2
     assert info["traces.replay"]["prefix_hit_share"] > 0
     assert 0 < info["traces.replay"]["expert_union_mean"] <= 8
     assert set(info["report"]["seconds"]) == {"serving"}
