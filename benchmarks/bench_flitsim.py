@@ -21,8 +21,8 @@ from benchmarks import common
 from benchmarks.common import time_us
 from repro.core import flitsim, mix_grid
 from repro.core.flitsim import (
-    ADAPTIVE_SIM, ANALYTIC, PALLAS_SIM, SIMULATORS, SYMMETRIC_PARAMS,
-    simulate_grid, sweep_perturbed,
+    ADAPTIVE_SIM, ANALYTIC, SIMULATORS, SYMMETRIC_PARAMS, simulate_grid,
+    sweep_perturbed,
 )
 from repro.core.flitsim import _sweep_impl as sweep
 from repro.core.flitsim import _sweep_pipelining_impl as sweep_pipelining
@@ -104,31 +104,6 @@ def run(rows: list):
                      f"cells={v['cells']};stragglers={v['stragglers']};"
                      f"cycles_to_convergence={hist}"))
 
-    # -- fused-kernel engine (SimConfig engine="pallas") on the same grid ---
-    # interpret-mode on CPU (the kernel bodies trace to XLA); the row pins
-    # numerical agreement and IDENTICAL design-space winners vs the fixed
-    # engine, plus the per-launch telemetry the TPU path reports
-    eff_pallas = np.asarray(sweep(mixes=mixes, sim=PALLAS_SIM).efficiency)
-    max_dev_p = float(np.max(np.abs(eff_fixed - eff_pallas)))
-    assert max_dev_p <= 1e-3, (
-        f"pallas engine deviates {max_dev_p:.2e} > 1e-3 from the fixed "
-        f"engine on the {n_points}-pt sweep")
-    assert (eff_fixed.argmax(axis=0) == eff_pallas.argmax(axis=0)).all(), (
-        "pallas engine flips a per-mix protocol winner vs the fixed engine")
-    us_pallas = time_us(
-        lambda: np.asarray(sweep(mixes=mixes, sim=PALLAS_SIM).efficiency),
-        warmup=1, iters=5)
-    rows.append((f"flitsim/sweep_pallas_{n_points}pt", us_pallas,
-                 f"fixed_us={us_batched:.0f};"
-                 f"adaptive_xla_us={us_adapt:.0f};"
-                 f"max_dev_vs_fixed={max_dev_p:.1e};winners=identical"))
-    for fam, v in sorted(flitsim.last_run_info().items()):
-        if v.get("mode") != "adaptive":
-            continue
-        rows.append((f"flitsim/pallas_{fam.split('.')[1]}", 0.0,
-                     f"engine={v['engine']};launches={v['launches']};"
-                     f"cycles_run={v['cycles_run']}"))
-
     # -- period-exact asymmetric cut: dense perturbation grid ---------------
     # [31 lane-count scales x 2 asym protocols x 41 mixes]; every mix has a
     # small credit denominator, so the detector closes the warm window at
@@ -144,23 +119,23 @@ def run(rows: list):
         return np.asarray(simulate_grid(asym_keys, gx41, gy41, [64.0],
                                         perturbations=perts_dense, sim=sim))
 
-    eff_fixed_d, eff_pallas_d = _dense(), _dense(PALLAS_SIM)
-    max_dev_d = float(np.max(np.abs(eff_fixed_d - eff_pallas_d)))
+    eff_fixed_d, eff_adapt_d = _dense(), _dense(ADAPTIVE_SIM)
+    max_dev_d = float(np.max(np.abs(eff_fixed_d - eff_adapt_d)))
     assert max_dev_d <= 1e-3, (
         f"period-exact engine deviates {max_dev_d:.2e} > 1e-3 on the "
         f"dense asymmetric grid")
     assert (eff_fixed_d.argmax(axis=1)
-            == eff_pallas_d.argmax(axis=1)).all(), (
+            == eff_adapt_d.argmax(axis=1)).all(), (
         "period-exact engine flips a protocol winner on the dense grid")
     us_fixed_d = time_us(_dense, warmup=1, iters=3)
-    us_pallas_d = time_us(lambda: _dense(PALLAS_SIM), warmup=1, iters=3)
-    speedup_d = us_fixed_d / us_pallas_d
+    us_adapt_d = time_us(lambda: _dense(ADAPTIVE_SIM), warmup=1, iters=3)
+    speedup_d = us_fixed_d / us_adapt_d
     if not common.SMOKE:
         assert speedup_d >= 2.5, (
             f"period-exact asymmetric cut only x{speedup_d:.2f} vs fixed "
             f"XLA on the {dense_cells}-cell grid (expected >= x2.5)")
     vi = flitsim.last_run_info()["flitsim.asymmetric"]
-    rows.append((f"flitsim/pallas_dense_asym_{dense_cells}pt", us_pallas_d,
+    rows.append((f"flitsim/periodic_dense_asym_{dense_cells}pt", us_adapt_d,
                  f"fixed_us={us_fixed_d:.0f};wall_speedup=x{speedup_d:.2f};"
                  f"max_dev_vs_fixed={max_dev_d:.1e};"
                  f"cycles_run={vi['cycles_run']}/{vi['horizon']};"
@@ -183,23 +158,24 @@ def run(rows: list):
                                 mixes=sym_mixes, backlogs=sym_bls,
                                 sim=sim).efficiency)
 
-    eff_fixed_s, eff_pallas_s = _dense_sym(), _dense_sym(PALLAS_SIM)
-    dev_s = float(np.max(np.abs(eff_fixed_s - eff_pallas_s)))
+    eff_fixed_s, eff_adapt_s = _dense_sym(), _dense_sym(ADAPTIVE_SIM)
+    dev_s = float(np.max(np.abs(eff_fixed_s - eff_adapt_s)))
     assert dev_s == 0.0, (
         f"symmetric period-exact engine deviates {dev_s:.2e} from the "
         f"fixed engine on the drained dense grid (expected BITWISE)")
     assert (eff_fixed_s.argmax(axis=0)
-            == eff_pallas_s.argmax(axis=0)).all(), (
+            == eff_adapt_s.argmax(axis=0)).all(), (
         "symmetric period-exact engine flips a protocol winner")
     us_fixed_s = time_us(_dense_sym, warmup=1, iters=3)
-    us_pallas_s = time_us(lambda: _dense_sym(PALLAS_SIM), warmup=1, iters=3)
-    speedup_s = us_fixed_s / us_pallas_s
+    us_adapt_s = time_us(lambda: _dense_sym(ADAPTIVE_SIM), warmup=1,
+                         iters=3)
+    speedup_s = us_fixed_s / us_adapt_s
     if not common.SMOKE:
         assert speedup_s >= 2.0, (
             f"symmetric period-exact cut only x{speedup_s:.2f} vs fixed "
             f"XLA on the {sym_cells}-cell grid (expected >= x2.0)")
     vs = flitsim.last_run_info()["flitsim.symmetric"]
-    rows.append(("flitsim/pallas_dense_sym_periodic", us_pallas_s,
+    rows.append(("flitsim/periodic_dense_sym", us_adapt_s,
                  f"cells={sym_cells};fixed_us={us_fixed_s:.0f};"
                  f"wall_speedup=x{speedup_s:.2f};"
                  f"max_dev_vs_fixed={dev_s:.1e};"
@@ -207,10 +183,10 @@ def run(rows: list):
                  f"stragglers={vs['stragglers']};"
                  f"n_periods={len(vs.get('periods', {}))}"))
 
-    # -- million-cell asymmetric grid: cycles/sec/cell per engine -----------
+    # -- million-cell asymmetric grid: cycles/sec/cell per mode -------------
     # the fixed engine is rate-measured at a reduced 256-access horizon
-    # (full 4096 x 1e6 cells is minutes of CPU); adaptive engines run the
-    # real 4096-access problem and report their own retired-cycle rate
+    # (full 4096 x 1e6 cells is minutes of CPU); the adaptive engine runs
+    # the real 4096-access problem and reports its own retired-cycle rate
     if not common.SMOKE:
         m_mixes = 41
         m_q = 1_000_000 // (len(asym_keys) * m_mixes) + 1   # -> 1,000,072
@@ -228,20 +204,13 @@ def run(rows: list):
         rate_fixed = 256 / (us_fixed_m * 1e-6)
         parts = [f"cells={m_cells}",
                  f"xla_fixed_256acc={rate_fixed:.0f}c/s/cell"]
-        eng_eff = {}
-        for label, s in (("xla_adaptive", ADAPTIVE_SIM),
-                         ("pallas", PALLAS_SIM)):
-            us_m = time_us(lambda s=s: _million(sim=s), warmup=1, iters=1)
-            vm = flitsim.last_run_info()["flitsim.asymmetric"]
-            eng_eff[label] = _million(sim=s)
-            parts.append(
-                f"{label}={vm['cycles_run'] / (us_m * 1e-6):.0f}c/s/cell"
-                f"(launches={vm['launches']},stragglers={vm['stragglers']})")
-            last_us = us_m
-        dev_m = float(np.max(np.abs(eng_eff["xla_adaptive"]
-                                    - eng_eff["pallas"])))
-        parts.append(f"xla_vs_pallas_dev={dev_m:.1e}")
-        rows.append((f"flitsim/million_cell_asym_{m_cells}", last_us,
+        us_m = time_us(lambda: _million(sim=ADAPTIVE_SIM), warmup=1,
+                       iters=1)
+        vm = flitsim.last_run_info()["flitsim.asymmetric"]
+        parts.append(
+            f"xla_adaptive={vm['cycles_run'] / (us_m * 1e-6):.0f}c/s/cell"
+            f"(launches={vm['launches']},stragglers={vm['stragglers']})")
+        rows.append((f"flitsim/million_cell_asym_{m_cells}", us_m,
                      ";".join(parts)))
 
     # -- backlog-sensitivity grid (symmetric family only) -------------------

@@ -49,25 +49,6 @@ def run(rows: list):
     us3 = time_us(fn3, la, bb, iters=3)
     rows.append(("kernels/rglru_scan", us3, "assoc_scan_oracle"))
 
-    from repro.kernels.flit_sim import ops as fs_ops
-    from repro.kernels.flit_sim import ref as fs_ref
-    from repro.core.flitsim import _asym_param_rows, AsymmetricLaneParams
-    from repro.core.traffic import mix_grid
-    gx, gy = mix_grid(41)
-    pstack = AsymmetricLaneParams.stack([AsymmetricLaneParams.lpddr6(),
-                                         AsymmetricLaneParams.hbm()])
-    cells = 2 * 41
-    tile, cpad = fs_ops.tile_for(cells)
-    prows = fs_ops.pad_cells(
-        _asym_param_rows(pstack, jnp.asarray(gx), jnp.asarray(gy)), cpad)
-    fn5 = jax.jit(lambda p: fs_ops.asymmetric_periodic_launch(
-        p, n_accesses=4096, tile=tile, cells=cells, interpret=True)[0])
-    us5 = time_us(fn5, prows, iters=5, min_total_us=10_000.0)
-    det = int((jnp.asarray(fn5(prows))[1, :cells] > 0.5).sum())
-    rows.append(("kernels/flit_sim_asym_periodic", us5,
-                 f"cells={cells};detected={det};"
-                 f"obs_steps={fs_ref.PERIOD_OBS};horizon=4096"))
-
     from repro.kernels.flit_pack.ref import pack_flits_ref, flits_needed
     n_lines = 15 * 64
     f = flits_needed(n_lines)

@@ -77,8 +77,8 @@ def main() -> None:
         # adaptive-vs-fixed speedup, the cycles-to-convergence
         # histograms, the streaming sharded-sweep rows (async prefetch
         # speedup + overlap fraction), and the serving trace-capacity
-        # rows (tokens/sec tied to sim_bandwidth_gbs) — the perf
-        # trajectory tracked in-repo (and uploaded per CI matrix cell)
+        # rows (tokens/sec tied to sim_bandwidth_gbs) — CPU timings,
+        # untracked, uploaded per CI matrix cell
         flit_rows = [{"name": n, "us_per_call": us, "derived": d}
                      for n, us, d in rows
                      if n.startswith(("flitsim/", "streaming/",

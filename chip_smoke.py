@@ -7,9 +7,9 @@ sizes, through its normal entry points.
 
 A  the ``--bridge`` design-space report (representative workloads) whose
    winner labels must equal ``experiments/golden/design_space_summary.json``;
-B  the fixed, adaptive and Pallas flit-simulator engines on four grids:
-   every engine within 1e-3 of the fixed scan (the reference) with the
-   same winners, and every ``flit_sim`` launch lowered to a Mosaic kernel;
+B  the fixed and adaptive flit-simulator engines on four grids: the
+   adaptive engine within 1e-3 of the fixed scan (the reference) with the
+   same winners;
 C  a streamed joint space of >= 10^6 cells at the default simulator
    horizons.
 
@@ -115,9 +115,9 @@ def _mixes(n: int):
 
 def engine_grids() -> dict:
     """name -> (axes, metric).  The drained symmetric grid and the dense
-    asymmetric perturbation grid are the periodic detectors' grids; the
-    125-point sweep runs the symmetric chunk kernel (backlog 64 is past
-    the periodic gate); the joint pipelining grid runs its chunk kernel."""
+    asymmetric perturbation grid are the periodic probes' grids; the
+    125-point sweep runs the symmetric chunked core (backlog 64 is past
+    the periodic gate); the joint pipelining grid runs its chunked core."""
     from repro.core import axis, flitsim
     perts = [{}] + [{"total_lanes": round(0.6 + 0.03 * q, 4)}
                     for q in range(30)]
@@ -138,33 +138,9 @@ def engine_grids() -> dict:
     }
 
 
-#: (engine family, program kind) of each flit_sim launch function
-LAUNCHES = {
-    "symmetric_chunk": ("flitsim.symmetric", "pallas-chunk"),
-    "symmetric_periodic": ("flitsim.symmetric", "periodic"),
-    "asymmetric_periodic": ("flitsim.asymmetric", "periodic"),
-    "pipelining_chunk": ("flitsim.pipelining", "pallas-chunk"),
-}
-
-
-def mosaic_launches() -> dict:
-    """launch -> whether every cached Pallas program of it holds a Mosaic
-    kernel (``tpu_custom_call``); None where none was compiled."""
-    from repro.core import space
-    progs = space.programs(sorted({fam for fam, _ in LAUNCHES.values()}))
-    out = {}
-    for name, (fam, kind) in LAUNCHES.items():
-        texts = [p.as_text() for k, p in progs.items()
-                 if k[0] == fam and kind in k and k[-1] == "pallas"]
-        out[name] = (all("tpu_custom_call" in t for t in texts)
-                     if texts else None)
-    return out
-
-
 def phase_b() -> dict:
-    from repro.core import ADAPTIVE_SIM, FIXED_SIM, PALLAS_SIM, DesignSpace
-    sims = {"fixed": FIXED_SIM, "adaptive": ADAPTIVE_SIM,
-            "pallas": PALLAS_SIM}
+    from repro.core import ADAPTIVE_SIM, FIXED_SIM, DesignSpace
+    sims = {"fixed": FIXED_SIM, "adaptive": ADAPTIVE_SIM}
     grids, checks = {}, {}
     for gname, (axes, metric) in engine_grids().items():
         vals, wins, rows = {}, {}, {}
@@ -184,29 +160,24 @@ def phase_b() -> dict:
                            if "protocol" in cold.dims else None)
             rows[ename] = timing
         ref = vals["fixed"]
-        for ename in ("adaptive", "pallas"):
-            dev = float(np.max(np.abs(vals[ename] - ref)))
-            rows[ename].update(
-                max_abs_dev_vs_fixed=dev,
-                bitwise_equal_cells=int(np.sum(vals[ename] == ref)),
-                cells=int(ref.size))
-            checks[f"{gname}/{ename}/within_{TOL:g}"] = dev <= TOL
-            if wins["fixed"] is not None:
-                checks[f"{gname}/{ename}/winners_identical"] = bool(
-                    np.array_equal(wins[ename], wins["fixed"]))
+        dev = float(np.max(np.abs(vals["adaptive"] - ref)))
+        rows["adaptive"].update(
+            max_abs_dev_vs_fixed=dev,
+            bitwise_equal_cells=int(np.sum(vals["adaptive"] == ref)),
+            cells=int(ref.size))
+        checks[f"{gname}/adaptive/within_{TOL:g}"] = dev <= TOL
+        if wins["fixed"] is not None:
+            checks[f"{gname}/adaptive/winners_identical"] = bool(
+                np.array_equal(wins["adaptive"], wins["fixed"]))
         grids[gname] = rows
-    mosaic = mosaic_launches()
-    for name, ok in mosaic.items():
-        checks[f"mosaic/{name}"] = ok is True
     # the CPU suite pins the symmetric periodic path BITWISE to the fixed
     # engine on the drained grid; the chip result is reported, the 1e-3
     # contract above is what is checked
-    sym = grids["sym_drained_297"]
-    pins = {e: sym[e]["bitwise_equal_cells"] == sym[e]["cells"]
-            for e in ("adaptive", "pallas")}
+    sym = grids["sym_drained_297"]["adaptive"]
     return {"phase": "B", "name": "engines_vs_fixed", "grids": grids,
-            "mosaic_launches": mosaic,
-            "sym_periodic_bitwise_pin_holds": pins, "checks": checks}
+            "sym_periodic_bitwise_pin_holds":
+                sym["bitwise_equal_cells"] == sym["cells"],
+            "checks": checks}
 
 
 # -- C: a streamed space at user size -----------------------------------------

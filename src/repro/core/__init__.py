@@ -35,14 +35,10 @@ cache and the same report numerics:
     ==================  =========================================  =======
     ``FIXED_SIM``       full-horizon XLA scan; bit-identical to    goldens,
     (default)           the seed goldens                           CI gates
-    ``ADAPTIVE_SIM``    chunked XLA cores, batched early exit +    CPU
-                        period-exact asymmetric detector;          sweeps
-                        <= ``tol`` deviation, several-x fewer
-                        sequential cycles
-    ``PALLAS_SIM``      same adaptive schedule through the fused   TPU,
-    ``SimConfig(        :mod:`repro.kernels.flit_sim` kernels —    dense
-    engine="pallas")``  ONE launch per chunk, state on-chip;       grids
-                        interpret-mode (traced to XLA) off-TPU
+    ``ADAPTIVE_SIM``    period-exact probes, then chunked XLA      sweeps
+                        cores with batched early exit; <= 1e-3
+                        deviation, several-x fewer sequential
+                        cycles
     ``SimConfig(        trace-scan cores for the ``trace`` axis:   serving
     trace_cycles=C)``   C cycles per phase, state carried across   traces
                         phase boundaries; ``None`` = full horizon
@@ -133,8 +129,8 @@ from repro.core.latency import (
 )
 from repro.core.space import (
     ADAPTIVE_SIM, Axis, AxisSet, DesignSpace, FIXED_SIM, OWN_MIX,
-    PALLAS_SIM, STREAM_FAMILIES, SimConfig, SpaceArray, SpaceResult,
-    StreamConfig, axis, cache_stats, clear_cache, joint_frontier, regimes,
+    STREAM_FAMILIES, SimConfig, SpaceArray, SpaceResult, StreamConfig,
+    axis, cache_stats, clear_cache, joint_frontier, regimes,
 )
 from repro.core.report import FrontierReport, ReportSpec, build_report
 from repro.core.streaming import StreamResult

@@ -63,10 +63,11 @@ batched early exit:
   unobserved tail ``[n, N]`` is padded with a triangularly-weighted
   trailing-window steady estimate (triangular weighting suppresses the
   periodic-aliasing error of short windows to second order);
-* a cell counts as converged when its report is stable to ``tol`` AND —
-  for the symmetric family — its queue/credit pools are not drifting
-  (slow write-buffer fill produces metastable plateaus that a pure
-  output-stability test cannot distinguish from steady state);
+* a cell counts as converged when its report is stable to
+  ``_ADAPTIVE_TOL`` (relative) AND — for the symmetric family — its
+  queue/credit pools are not drifting (slow write-buffer fill produces
+  metastable plateaus that a pure output-stability test cannot
+  distinguish from steady state);
 * the loop exits when every cell converged, when the straggler count
   drops below the escalation budget (large grids only — the stragglers
   are then re-simulated EXACTLY at the full fixed horizon in a tiny
@@ -93,8 +94,7 @@ from jax.profiler import TraceAnnotation
 
 from repro.core import space as space_mod
 from repro.core.space import (
-    ADAPTIVE_SIM, FIXED_SIM, PALLAS_SIM, CacheStats, SimConfig,
-    cached_program,
+    ADAPTIVE_SIM, FIXED_SIM, CacheStats, SimConfig, cached_program,
 )
 from repro.core.protocols.chi_ucie import CHIOnUCIe
 from repro.core.protocols.cxl_mem import CXLMemOnUCIe
@@ -516,6 +516,13 @@ def _pipelining_grid(ks, ucie_line_uis, device_line_uis, *, max_k: int,
 # itself; unrolled, a phase costs about half the device time on a v5e.
 
 _TRACE_UNROLL = 8
+#: the adaptive cores' loop constants: the configured chunk (cycles per
+#: convergence check, before :func:`_divisor_chunk` fits it to the
+#: horizon), the inner scan's unroll, and the relative report-stability
+#: tolerance each cell must meet to converge
+_ADAPTIVE_CHUNK = 128
+_ADAPTIVE_UNROLL = 4
+_ADAPTIVE_TOL = 1e-3
 
 
 def _symmetric_trace_point(p, xs, ys, bls, *, n_phases: int, cycles: int):
@@ -950,11 +957,10 @@ def last_run_info() -> Dict[str, Dict[str, Any]]:
     ``converged_cycles`` histogram ({cycles: cell count}; stragglers and
     horizon-exits count under ``"horizon"``).
 
-    Engine telemetry (PR 6): ``engine`` (``"xla"`` / ``"pallas"``),
-    ``launches`` (device programs the runner dispatched — the pallas host
-    loop issues one per chunk plus one per escalation pass; the XLA
-    ``while_loop`` cores are a single launch) and ``elapsed_s`` (runner
-    wall time, device work blocked to completion).
+    Engine telemetry: ``launches`` (device programs the runner
+    dispatched: 1 for the probe or the ``while_loop`` core, 2 when a
+    straggler-escalation pass ran) and ``elapsed_s`` (runner wall time,
+    device work blocked to completion).
     The asymmetric periodic detector additionally reports a ``periods``
     histogram ({detected credit period: cell count}).
 
@@ -1009,12 +1015,12 @@ def last_run_info() -> Dict[str, Dict[str, Any]]:
 
 
 def _record_adaptive(family: str, horizon: int, chunk: int, k_exit,
-                     conv_at, stragglers: int, *, engine: str = "xla",
-                     launches: int = 1, elapsed_s: Optional[float] = None,
+                     conv_at, stragglers: int, *, launches: int = 1,
+                     elapsed_s: Optional[float] = None,
                      probe_out=None) -> None:
     _LAST_RUN_INFO[family] = {
         "mode": "adaptive", "horizon": int(horizon), "chunk": int(chunk),
-        "stragglers": int(stragglers), "engine": engine,
+        "stragglers": int(stragglers),
         "launches": int(launches), "elapsed_s": elapsed_s,
         "_k_exit": k_exit, "_conv_at": conv_at, "_probe_out": probe_out,
     }
@@ -1083,7 +1089,6 @@ def _record_trace(family: str, phases: int, cycles: int,
         "cycles_run": int(phases) * int(cycles),
         "trace_cells": int(cells),
         "state_carry_depth": (int(phases) - 1) * int(cycles),
-        "engine": "xla",
     }
 
 
@@ -1132,18 +1137,17 @@ def _pad_pow2(idx: np.ndarray) -> np.ndarray:
                                           axis=0)])
 
 
-# -- fused-kernel engine (SimConfig engine="pallas") + periodic detector ------
+# -- period-exact probes ------------------------------------------------------
 #
-# The row-stacked operand layouts and the per-chunk compute contracts live
-# in repro.kernels.flit_sim (ref.py documents them; kernel.py is the
-# Pallas transcription sharing the same compute bodies).  The kernels
-# package imports this module for the step functions, so everything below
-# imports it lazily.
+# The row-stacked operand layouts and the periodic compute bodies live in
+# repro.kernels.flit_sim.ref (its docstring documents them).  That module
+# imports this one for the step functions, so everything below imports it
+# lazily.
 #
-# The asymmetric family additionally gets a PERIOD-EXACT detector (both
-# engines): the credit accumulator advances by the rational read fraction
-# x/(x+y) each access, so the credit state — which alone determines every
-# future lane increment — is exactly periodic with denominator
+# The asymmetric family gets a PERIOD-EXACT detector: the credit
+# accumulator advances by the rational read fraction x/(x+y) each access,
+# so the credit state — which alone determines every future lane
+# increment — is exactly periodic with denominator
 # q = (x+y)/gcd(x,y).  The runner observes ~2 maximal periods
 # (ref.PERIOD_OBS sequential steps), detects each cell's period from the
 # credit phase, extrapolates the per-lane busy times exactly to the full
@@ -1163,7 +1167,7 @@ def _pad_pow2(idx: np.ndarray) -> np.ndarray:
 
 
 def _sym_param_rows(pstack, x, y, backlogs):
-    """Row-stack a symmetric grid into the kernels' [SYM_ROWS, P*B*M]
+    """Row-stack a symmetric grid into the probe's [SYM_ROWS, P*B*M]
     layout (cell order matches ``rep.reshape(P, B, M)``)."""
     from repro.kernels.flit_sim import ref as fs_ref
     P, B, M = pstack.g_slots.shape[0], backlogs.shape[0], x.shape[0]
@@ -1188,48 +1192,20 @@ def _asym_param_rows(pstack, x, y):
     return jnp.stack(rows + [pad] * (fs_ref.ASYM_ROWS - len(rows)))
 
 
-def _pipe_param_rows(ks, ucie_line_uis, device_line_uis):
-    """Row-stack a pipelining grid into [ASYM_ROWS, K*U*D]."""
-    from repro.kernels.flit_sim import ref as fs_ref
-    Kk, U, Dn = (ks.shape[0], ucie_line_uis.shape[0],
-                 device_line_uis.shape[0])
-    rows = [jnp.repeat(_f32(ks), U * Dn),
-            jnp.tile(jnp.repeat(_f32(ucie_line_uis), Dn), Kk),
-            jnp.tile(_f32(device_line_uis), Kk * U)]
-    pad = jnp.zeros_like(rows[0])
-    return jnp.stack(rows + [pad] * (fs_ref.ASYM_ROWS - len(rows)))
-
-
-def _scal_row(values) -> jnp.ndarray:
-    """Broadcast-scalar [1, SCAL_COLS] operand from leading values."""
-    from repro.kernels.flit_sim import ref as fs_ref
-    row = np.zeros((1, fs_ref.SCAL_COLS), np.float32)
-    row[0, :len(values)] = values
-    return jnp.asarray(row)
-
-
 def _run_asymmetric_periodic(pstack, x, y, horizon: int, sim: SimConfig):
     """Period-exact asymmetric run (one launch + exact escalation of
     undetected cells).  Returns the report grid, or ``None`` when the
     grid is mostly aperiodic and the chunked core is the better tool."""
-    from repro.kernels.flit_sim import ops as fs_ops
     from repro.kernels.flit_sim import ref as fs_ref
     P, M = pstack.total_lanes.shape[0], x.shape[0]
     cells = P * M
     t0 = time.perf_counter()
+
     # the row-stacking runs INSIDE the cached program: the whole periodic
     # run is one dispatch from the host's point of view
-    if sim.engine == "pallas":
-        tile, cpad = fs_ops.tile_for(cells, fs_ops.ASYM_PERIODIC_MAX_TILE)
-
-        def build(ps, xs, ys):
-            rows = fs_ops.pad_cells(_asym_param_rows(ps, xs, ys), cpad)
-            return fs_ops.asymmetric_periodic_launch(
-                rows, n_accesses=horizon, tile=tile, cells=cells)[0]
-    else:
-        def build(ps, xs, ys):
-            return fs_ref.asymmetric_periodic_compute(
-                _asym_param_rows(ps, xs, ys), n_accesses=horizon)
+    def build(ps, xs, ys):
+        return fs_ref.asymmetric_periodic_compute(
+            _asym_param_rows(ps, xs, ys), n_accesses=horizon)
     fn = cached_program("flitsim.asymmetric",
                         (P, M, horizon, "periodic") + sim.key(),
                         build, (pstack, x, y), path="probe")
@@ -1255,7 +1231,7 @@ def _run_asymmetric_periodic(pstack, x, y, horizon: int, sim: SimConfig):
     jax.block_until_ready(rep)
     conv_at = np.where(det_np, 1, -1).astype(np.int32).reshape(P, M)
     _record_adaptive("flitsim.asymmetric", horizon, fs_ref.PERIOD_OBS, 1,
-                     conv_at, undet, engine=sim.engine, launches=launches,
+                     conv_at, undet, launches=launches,
                      elapsed_s=time.perf_counter() - t0,
                      probe_out=out)
     return rep
@@ -1270,24 +1246,16 @@ def _run_symmetric_periodic(pstack, x, y, backlogs, horizon: int,
     engine's report bit-for-bit.  Returns the report grid, or ``None``
     when the grid is mostly aperiodic (saturated backlogs) and the
     chunked core is the better tool."""
-    from repro.kernels.flit_sim import ops as fs_ops
     from repro.kernels.flit_sim import ref as fs_ref
     P, B, M = pstack.g_slots.shape[0], backlogs.shape[0], x.shape[0]
     cells = P * B * M
     t0 = time.perf_counter()
+
     # the row-stacking runs INSIDE the cached program: the whole periodic
     # run is one dispatch from the host's point of view
-    if sim.engine == "pallas":
-        tile, cpad = fs_ops.tile_for(cells, fs_ops.SYM_PERIODIC_MAX_TILE)
-
-        def build(ps, xs, ys, bs):
-            rows = fs_ops.pad_cells(_sym_param_rows(ps, xs, ys, bs), cpad)
-            return fs_ops.symmetric_periodic_launch(
-                rows, n_flits=horizon, tile=tile, cells=cells)[0]
-    else:
-        def build(ps, xs, ys, bs):
-            return fs_ref.symmetric_periodic_compute(
-                _sym_param_rows(ps, xs, ys, bs), n_flits=horizon)
+    def build(ps, xs, ys, bs):
+        return fs_ref.symmetric_periodic_compute(
+            _sym_param_rows(ps, xs, ys, bs), n_flits=horizon)
     fn = cached_program("flitsim.symmetric",
                         (P, B, M, horizon, "periodic") + sim.key(),
                         build, (pstack, x, y, backlogs), path="probe")
@@ -1314,143 +1282,9 @@ def _run_symmetric_periodic(pstack, x, y, backlogs, horizon: int,
     jax.block_until_ready(rep)
     conv_at = np.where(det_np, 1, -1).astype(np.int32).reshape(P, B, M)
     _record_adaptive("flitsim.symmetric", horizon, fs_ref.SYM_PERIOD_OBS,
-                     1, conv_at, undet, engine=sim.engine,
-                     launches=launches,
+                     1, conv_at, undet, launches=launches,
                      elapsed_s=time.perf_counter() - t0,
                      probe_out=out)
-    return rep
-
-
-def _run_symmetric_pallas(pstack, x, y, backlogs, horizon: int,
-                          chunk: int, sim: SimConfig):
-    """Host-driven adaptive symmetric loop on the fused chunk kernel: one
-    launch per chunk; report / drift / convergence evaluated in-kernel;
-    the host reads back one flag row per chunk to steer the early exit.
-    Chunk-boundary histories stay as a host-side list of device rows (the
-    kernel receives exactly the rows the report formula needs)."""
-    from repro.kernels.flit_sim import ops as fs_ops
-    P, B, M = pstack.g_slots.shape[0], backlogs.shape[0], x.shape[0]
-    cells = P * B * M
-    K = horizon // chunk
-    K0 = max(K // 4, 1)
-    min_k = max(_MIN_EXIT_CHUNKS, K0 + 1)
-    budget = _escalation_budget(cells, chunk, horizon)
-    t0 = time.perf_counter()
-    tile, cpad = fs_ops.tile_for(cells)
-    params = fs_ops.pad_cells(_sym_param_rows(pstack, x, y, backlogs),
-                              cpad)
-    state = jnp.zeros((fs_ops.SYM_ROWS, cpad), jnp.float32)
-    zrow = jnp.zeros((1, cpad), jnp.float32)
-    z5 = jnp.zeros((5, cpad), jnp.float32)
-    z6 = jnp.zeros((6, cpad), jnp.float32)
-    Dh, TDh, Ph = [zrow], [zrow], [z5]
-
-    def hist_for(k: int):
-        m = max(k - 4, (k + 1) // 2)
-        mid = (m + k + 1) // 2
-        return m, mid, jnp.concatenate([
-            Ph[max(k - _DRIFT_SPAN, 0)],
-            Dh[m] if m < k else zrow, TDh[m] if m < k else zrow,
-            Dh[mid] if mid < k else zrow, TDh[mid] if mid < k else zrow,
-            Dh[K0] if k > K0 else zrow, z6])
-
-    def scal_for(k: int, m: int, mid: int):
-        return _scal_row([k, m, mid, K0, K, chunk, sim.tol,
-                          1.0 if (k >= min_k and k > _DRIFT_SPAN) else 0.0,
-                          1.0 if k >= K else 0.0, _DRIFT_TOL_SLOTS])
-
-    m1, mid1, hist1 = hist_for(1)
-    launch = cached_program(
-        "flitsim.symmetric",
-        (P, B, M, horizon, "pallas-chunk") + sim.key(),
-        functools.partial(fs_ops.symmetric_chunk_launch, chunk=chunk,
-                          tile=tile, cells=cells),
-        (params, state, hist1, scal_for(1, m1, mid1)), path="pallas_chunk")
-    conv_at = np.full(cells, -1, np.int32)
-    conv_np = np.zeros(cells, bool)
-    k = 0
-    while k < K:
-        k += 1
-        m, mid, hist = hist_for(k)
-        state, conv = launch(params, state, hist, scal_for(k, m, mid))
-        Dh.append(state[7:8])
-        TDh.append(state[8:9])
-        Ph.append(state[0:5])
-        with TraceAnnotation("repro.engine.readback",
-                             family="flitsim.symmetric"):
-            conv_np = np.asarray(conv)
-        conv_at[(conv_at < 0) & conv_np] = k
-        if int((~conv_np).sum()) <= budget:
-            break
-    rep = state[10, :cells].reshape(P, B, M)
-    stragglers = int((~conv_np).sum()) if budget > 0 else 0
-    launches = k
-    if stragglers:
-        rep = _escalate_stragglers(
-            "flitsim.symmetric",
-            functools.partial(_symmetric_cells_grid, n_flits=horizon),
-            horizon, rep, conv_np.reshape(P, B, M),
-            lambda idx: (_gather_cells(pstack, idx[:, 0]),
-                         jnp.asarray(np.asarray(x)[idx[:, 2]]),
-                         jnp.asarray(np.asarray(y)[idx[:, 2]]),
-                         jnp.asarray(np.asarray(backlogs)[idx[:, 1]])))
-        launches += 1
-    jax.block_until_ready(rep)
-    _record_adaptive("flitsim.symmetric", horizon, chunk, k,
-                     conv_at.reshape(P, B, M), stragglers,
-                     engine="pallas", launches=launches,
-                     elapsed_s=time.perf_counter() - t0)
-    return rep
-
-
-def _run_pipelining_pallas(ks, ucie_line_uis, device_line_uis,
-                           horizon: int, chunk: int, sim: SimConfig):
-    """Host-driven adaptive pipelining loop on the fused chunk kernel
-    (same shape as the symmetric loop; no drift guard / escalation —
-    the rotation report converges monotonically)."""
-    from repro.kernels.flit_sim import ops as fs_ops
-    Kk, U, Dn = (ks.shape[0], ucie_line_uis.shape[0],
-                 device_line_uis.shape[0])
-    cells = Kk * U * Dn
-    K = horizon // chunk
-    min_k = min(_MIN_EXIT_CHUNKS, K)
-    t0 = time.perf_counter()
-    tile, cpad = fs_ops.tile_for(cells)
-    params = fs_ops.pad_cells(
-        _pipe_param_rows(ks, ucie_line_uis, device_line_uis), cpad)
-    state = jnp.zeros((fs_ops.PIPE_ROWS, cpad), jnp.float32)
-    hist = jnp.zeros((fs_ops.ASYM_ROWS, cpad), jnp.float32)
-
-    def scal_for(k: int):
-        return _scal_row([k, K, chunk, sim.tol,
-                          1.0 if k >= min_k else 0.0,
-                          1.0 if k >= K else 0.0, horizon])
-
-    launch = cached_program(
-        "flitsim.pipelining",
-        (Kk, U, Dn, horizon, "pallas-chunk") + sim.key(),
-        functools.partial(fs_ops.pipelining_chunk_launch, chunk=chunk,
-                          tile=tile, cells=cells),
-        (params, state, hist, scal_for(1)), path="pallas_chunk")
-    conv_at = np.full(cells, -1, np.int32)
-    k = 0
-    while k < K:
-        k += 1
-        state, conv = launch(params, state, hist, scal_for(k))
-        if k == 1:      # T1 anchor for the linear-growth extrapolation
-            hist = jnp.concatenate(
-                [state[8:9], jnp.zeros((7, cpad), jnp.float32)])
-        with TraceAnnotation("repro.engine.readback",
-                             family="flitsim.pipelining"):
-            conv_np = np.asarray(conv)
-        conv_at[(conv_at < 0) & conv_np] = k
-        if int((~conv_np).sum()) == 0:
-            break
-    rep = state[10, :cells].reshape(Kk, U, Dn)
-    jax.block_until_ready(rep)
-    _record_adaptive("flitsim.pipelining", horizon, chunk, k,
-                     conv_at.reshape(Kk, U, Dn), 0, engine="pallas",
-                     launches=k, elapsed_s=time.perf_counter() - t0)
     return rep
 
 
@@ -1465,7 +1299,7 @@ def _run_symmetric(pstack, x, y, backlogs, n_flits: int,
             (pstack, x, y, backlogs), path="fixed")
         return fn(pstack, x, y, backlogs)
     horizon = sim.horizon(n_flits)
-    chunk = _divisor_chunk(horizon, sim.chunk)
+    chunk = _divisor_chunk(horizon, _ADAPTIVE_CHUNK)
     if chunk < 8:               # divisor-poor horizon: adaptive degrades
         return _run_symmetric(pstack, x, y, backlogs, horizon,
                               sim=FIXED_SIM)
@@ -1475,7 +1309,7 @@ def _run_symmetric(pstack, x, y, backlogs, n_flits: int,
     if (horizon // 4 >= SYM_PERIOD_OBS
             and float(np.max(np.asarray(backlogs)))
             <= SYM_PERIODIC_MAX_BACKLOG):
-        # period-exact cut (both engines): observe the pool-state window
+        # period-exact cut: observe the pool-state window
         # before the warm window opens and extrapolate bitwise; falls
         # through to the chunked core on mostly aperiodic grids (None).
         # Saturated grids skip the probe outright (see the
@@ -1487,9 +1321,6 @@ def _run_symmetric(pstack, x, y, backlogs, n_flits: int,
         if rep is not None:
             return rep
     with TraceAnnotation("repro.engine.core", family="flitsim.symmetric"):
-        if sim.engine == "pallas":
-            return _run_symmetric_pallas(pstack, x, y, backlogs, horizon,
-                                         chunk, sim)
         return _run_symmetric_core(pstack, x, y, backlogs, horizon, chunk,
                                    sim)
 
@@ -1504,8 +1335,8 @@ def _run_symmetric_core(pstack, x, y, backlogs, horizon: int, chunk: int,
     fn = cached_program(
         "flitsim.symmetric", (P, B, M, horizon) + sim.key(),
         functools.partial(_symmetric_grid_adaptive, n_flits=horizon,
-                          chunk=chunk, unroll=int(sim.unroll),
-                          tol=float(sim.tol), budget=budget),
+                          chunk=chunk, unroll=_ADAPTIVE_UNROLL,
+                          tol=_ADAPTIVE_TOL, budget=budget),
         (pstack, x, y, backlogs), path="core")
     rep, conv, k_exit, conv_at = fn(pstack, x, y, backlogs)
     stragglers = 0
@@ -1525,7 +1356,7 @@ def _run_symmetric_core(pstack, x, y, backlogs, horizon: int, chunk: int,
                              jnp.asarray(np.asarray(backlogs)[idx[:, 1]])))
     jax.block_until_ready(rep)
     _record_adaptive("flitsim.symmetric", horizon, chunk, k_exit, conv_at,
-                     stragglers, engine="xla",
+                     stragglers,
                      launches=1 + (1 if stragglers else 0),
                      elapsed_s=time.perf_counter() - t0)
     return rep
@@ -1542,12 +1373,12 @@ def _run_asymmetric(pstack, x, y, n_accesses: int,
             (pstack, x, y), path="fixed")
         return fn(pstack, x, y)
     horizon = sim.horizon(n_accesses)
-    chunk = _divisor_chunk(horizon, sim.chunk)
+    chunk = _divisor_chunk(horizon, _ADAPTIVE_CHUNK)
     if chunk < 8:
         return _run_asymmetric(pstack, x, y, horizon, sim=FIXED_SIM)
     from repro.kernels.flit_sim.ref import PERIOD_OBS
     if horizon >= PERIOD_OBS:
-        # period-exact cut (both engines): observe ~2 credit periods and
+        # period-exact cut: observe ~2 credit periods and
         # extrapolate; falls through to the chunked core on mostly
         # aperiodic grids (None)
         with TraceAnnotation("repro.engine.probe",
@@ -1569,8 +1400,8 @@ def _run_asymmetric_core(pstack, x, y, horizon: int, chunk: int,
     fn = cached_program(
         "flitsim.asymmetric", (P, M, horizon) + sim.key(),
         functools.partial(_asymmetric_grid_adaptive, n_accesses=horizon,
-                          chunk=chunk, unroll=int(sim.unroll),
-                          tol=float(sim.tol), budget=budget),
+                          chunk=chunk, unroll=_ADAPTIVE_UNROLL,
+                          tol=_ADAPTIVE_TOL, budget=budget),
         (pstack, x, y), path="core")
     rep, conv, k_exit, conv_at = fn(pstack, x, y)
     stragglers = 0
@@ -1590,7 +1421,7 @@ def _run_asymmetric_core(pstack, x, y, horizon: int, chunk: int,
                              jnp.asarray(np.asarray(y)[idx[:, 1]])))
     jax.block_until_ready(rep)
     _record_adaptive("flitsim.asymmetric", horizon, chunk, k_exit, conv_at,
-                     stragglers, engine="xla",
+                     stragglers,
                      launches=1 + (1 if stragglers else 0),
                      elapsed_s=time.perf_counter() - t0)
     return rep
@@ -1608,29 +1439,23 @@ def _run_pipelining(ks, ucie_line_uis, device_line_uis, max_k: int,
             (ks, ucie_line_uis, device_line_uis), path="fixed")
         return fn(ks, ucie_line_uis, device_line_uis)
     horizon = sim.horizon(n_lines)
-    chunk = _divisor_chunk(horizon, sim.chunk)
+    chunk = _divisor_chunk(horizon, _ADAPTIVE_CHUNK)
     if chunk < 8:
         return _run_pipelining(ks, ucie_line_uis, device_line_uis, max_k,
                                horizon, sim=FIXED_SIM)
     with TraceAnnotation("repro.engine.core", family="flitsim.pipelining"):
-        if sim.engine == "pallas":
-            from repro.kernels.flit_sim.ref import PIPE_MAX_K
-            if max_k <= PIPE_MAX_K:     # kernel holds PIPE_MAX_K device rows
-                return _run_pipelining_pallas(ks, ucie_line_uis,
-                                              device_line_uis, horizon,
-                                              chunk, sim)
         t0 = time.perf_counter()
         fn = cached_program(
             "flitsim.pipelining", shape + (max_k, horizon) + sim.key(),
             functools.partial(_pipelining_grid_adaptive, max_k=max_k,
                               n_lines=horizon, chunk=chunk,
-                              unroll=int(sim.unroll), tol=float(sim.tol)),
+                              unroll=_ADAPTIVE_UNROLL, tol=_ADAPTIVE_TOL),
             (ks, ucie_line_uis, device_line_uis), path="core")
         rep, conv, k_exit, conv_at = fn(ks, ucie_line_uis, device_line_uis)
         jax.block_until_ready(rep)
     _record_adaptive("flitsim.pipelining", horizon, chunk, k_exit, conv_at,
                      0,                 # exits only converged / at horizon
-                     engine="xla", launches=1,
+                     launches=1,
                      elapsed_s=time.perf_counter() - t0)
     return rep
 

@@ -143,32 +143,20 @@ class SimConfig:
 
     ``mode="fixed"`` runs the full fixed-horizon ``lax.scan`` — bit-identical
     to the pre-config engine and to every pinned golden.  ``mode="adaptive"``
-    runs the chunked early-exit cores: a ``lax.while_loop`` over chunks of
-    ``chunk`` cycles (inner ``lax.scan`` with ``unroll=``) that stops as
-    soon as every grid cell's reconstructed fixed-window estimate is stable
-    to within ``tol`` (relative), or the horizon is hit.
+    runs the periodic probes and the chunked early-exit cores: a
+    ``lax.while_loop`` over chunks of cycles that stops as soon as every
+    grid cell's reconstructed fixed-window estimate is stable, or the
+    horizon is hit.  The loop constants (chunk, unroll, tolerance) live
+    beside the cores in :mod:`repro.core.flitsim`.
 
     ``max_cycles`` overrides the per-family horizon (defaults: the caller's
-    ``n_flits`` / ``n_accesses`` / ``n_lines``); ``chunk`` is shrunk per
-    family to an exact divisor of the horizon (>= 8 chunks per run).  The
-    config participates in the shared compile-cache key (:meth:`key`), so
-    alternating configs never invalidates other configs' warm executables.
-
-    ``engine`` picks the adaptive execution backend: ``"xla"`` (default)
-    runs the chunked ``lax.while_loop`` cores; ``"pallas"`` runs the fused
-    single-launch-per-chunk Pallas kernels from
-    :mod:`repro.kernels.flit_sim` (``interpret=True`` off-TPU, real
-    lowering on TPU).  The fixed mode is engine-independent by design —
-    it must stay bit-identical to every pinned golden — so
-    ``engine="pallas"`` requires ``mode="adaptive"``.
+    ``n_flits`` / ``n_accesses`` / ``n_lines``).  The config participates
+    in the shared compile-cache key (:meth:`key`), so alternating configs
+    never invalidates other configs' warm executables.
     """
 
     mode: str = "fixed"
-    chunk: int = 128
-    unroll: int = 4
-    tol: float = 1e-3
     max_cycles: Optional[int] = None
-    engine: str = "xla"
     #: cycles simulated per trace PHASE (``trace``-axis evaluations only);
     #: ``None`` uses the family's static horizon, which makes a default
     #: single-phase trace bit-identical to its static (mix, backlog) cell
@@ -178,22 +166,6 @@ class SimConfig:
         if self.mode not in ("fixed", "adaptive"):
             raise ValueError(f"SimConfig.mode must be 'fixed' or "
                              f"'adaptive', got {self.mode!r}")
-        if self.engine not in ("xla", "pallas"):
-            raise ValueError(f"SimConfig.engine must be 'xla' or "
-                             f"'pallas', got {self.engine!r}")
-        if self.engine == "pallas" and self.mode != "adaptive":
-            raise ValueError(
-                "SimConfig(engine='pallas') requires mode='adaptive': the "
-                "fixed mode is pinned bit-identical to the golden numerics "
-                "and always runs the XLA scan core")
-        if int(self.chunk) < 8:
-            raise ValueError(f"SimConfig.chunk must be >= 8, got "
-                             f"{self.chunk}")
-        if int(self.unroll) < 1:
-            raise ValueError(f"SimConfig.unroll must be >= 1, got "
-                             f"{self.unroll}")
-        if not self.tol > 0.0:
-            raise ValueError(f"SimConfig.tol must be > 0, got {self.tol}")
         if self.max_cycles is not None and int(self.max_cycles) < 1:
             raise ValueError(f"SimConfig.max_cycles must be >= 1, got "
                              f"{self.max_cycles}")
@@ -204,7 +176,7 @@ class SimConfig:
     def horizon(self, default: int) -> int:
         """Resolved horizon for a family whose fixed length is ``default``.
 
-        The adaptive runner shrinks ``chunk`` to an exact divisor of the
+        The adaptive runner shrinks its chunk to an exact divisor of the
         horizon (at least 8 chunks per run) so the chunked loop can always
         reproduce the fixed window exactly at full depth.
         """
@@ -221,19 +193,14 @@ class SimConfig:
             else (int(self.trace_cycles),)
         if self.mode == "fixed":
             return ("fixed",) + trace
-        return ("adaptive", int(self.chunk), int(self.unroll),
-                float(self.tol), self.max_cycles, self.engine) + trace
+        return ("adaptive", self.max_cycles) + trace
 
 
 #: the default config: bit-identical fixed-horizon simulation
 FIXED_SIM = SimConfig()
 #: convergence-adaptive early-exit simulation (benchmarks / explorer
-#: default; <= tol-scale deviation from FIXED_SIM)
+#: default; <= 1e-3 deviation from FIXED_SIM)
 ADAPTIVE_SIM = SimConfig(mode="adaptive")
-#: convergence-adaptive simulation on the fused Pallas kernels — one
-#: launch per chunk instead of ~chunk dispatched ops (<= tol-scale
-#: deviation from FIXED_SIM, same gate as ADAPTIVE_SIM)
-PALLAS_SIM = SimConfig(mode="adaptive", engine="pallas")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,12 +1,14 @@
-"""Pure-jnp reference for the fused flit-simulator chunk contracts.
+"""Period-exact probe bodies of the adaptive flit simulators.
 
-The Pallas kernels in :mod:`repro.kernels.flit_sim.kernel` and this
-oracle share the compute bodies below verbatim — the kernel adds only the
-tiling / ref plumbing — so kernel-vs-ref agreement is by construction and
-the tests pin it bit-for-bit in ``interpret=True`` mode.
+The adaptive runners in :mod:`repro.core.flitsim` compile these two
+bodies as their probe programs (``_run_asymmetric_periodic`` and
+``_run_symmetric_periodic``): one launch observes a short window of the
+simulator, certifies each cell's period and extrapolates its report to
+the full horizon; the cells it cannot certify are escalated by the
+caller.
 
-Every contract works on ROW-STACKED f32 arrays ``[rows, cells]`` (cells
-last so the vector axis maps onto TPU lanes).  The row layouts:
+Both contracts work on ROW-STACKED f32 arrays ``[rows, cells]`` (cells
+last, so the cell axis maps onto the TPU lanes).  The row layouts:
 
 symmetric ``params`` [16, C] (pad rows zero)::
 
@@ -14,40 +16,10 @@ symmetric ``params`` [16, C] (pad rows zero)::
            (g_slots .. write_buffer_lines)
     11 x   12 y   13 backlog
 
-symmetric ``state`` [16, C] — also the chunk output layout::
-
-    0..6   core (rq, wq, wdata, rdata, resp, cr, cw)
-    7 D    cumulative data slots        8 TD   time-weighted sum(t * d_t)
-    9 t    cycles simulated             10 rep  last report
-    11 conv  convergence flag (output only)
-
-symmetric ``hist`` [16, C] — chunk-boundary history rows the host gathers
-from its per-chunk list (one launch per chunk keeps no cross-chunk
-history on the device)::
-
-    0..4   pools (rq, wq, wdata, rdata, resp) at chunk max(k-3, 0)
-    5 D_m  6 TD_m  7 D_mid  8 TD_mid   (zeros when m == k / mid == k:
-           the kernel substitutes the freshly computed accumulators)
-    9 D_K0 (zeros when k <= K0)
-
-symmetric ``scal`` [1, 128] broadcast scalars::
-
-    0 k  1 m  2 mid  3 K0  4 K  5 chunk  6 tol
-    7 exit_ok (k >= min_k and k > drift span)   8 at_horizon (k == K)
-    9 drift_tol (slots / chunk)
-
 asymmetric ``params`` [8, C]: AsymmetricLaneParams fields in dataclass
-order (total_lanes .. access_bits) then 6 x, 7 y.  Output [8, C]:
-0 rep, 1 detected, 2 period.
+order (total_lanes .. access_bits) then 6 x, 7 y.
 
-symmetric periodic: input is the symmetric ``params`` [16, C] stack;
-output [8, C]: 0 rep, 1 detected, 2 period (pad rows zero).
-
-pipelining ``params`` [8, C]: 0 k_devices, 1 ucie_line_ui,
-2 device_line_ui.  ``state`` [16, C]: 0..7 dev_ready (padded to 8
-devices), 8 link_free, 9 idx, 10 rep; output adds 11 conv.  ``hist``
-[8, C]: 0 T1 (link free time after chunk 1; zeros at k == 1).  ``scal``
-[1, 128]: 0 k, 1 K, 2 chunk, 3 tol, 4 exit_ok, 5 at_horizon, 6 n_lines.
+Both outputs are [8, C]: 0 rep, 1 detected, 2 period (pad rows zero).
 """
 from __future__ import annotations
 
@@ -62,9 +34,6 @@ from repro.core.flitsim import (
 #: rows per stacked operand (f32 sublane multiple)
 SYM_ROWS = 16
 ASYM_ROWS = 8
-PIPE_ROWS = 16
-#: broadcast-scalar operand shape (one full lane row)
-SCAL_COLS = 128
 
 #: largest credit-cycle denominator the period detector resolves; the
 #: observation run is ~2 such periods (warm prefix + one full window)
@@ -95,12 +64,6 @@ SYM_PERIODIC_ROWS = 8
 #: backlog exceeds this skip straight to the chunked core.  Purely a
 #: cost heuristic — detection itself stays an exact state match.
 SYM_PERIODIC_MAX_BACKLOG = 4.0
-
-#: device-ready table width shared with flitsim._PIPELINING_PAD_K
-PIPE_MAX_K = 8
-
-#: drift-guard pool-snapshot span (mirrors flitsim._DRIFT_SPAN)
-DRIFT_SPAN = 3.0
 
 
 # -- periodic observation helpers --------------------------------------------
@@ -146,61 +109,6 @@ def _smallest_lag(ok):
                   axis=0)
     detected = lag <= PERIOD_MAX
     return jnp.where(detected, lag, 1.0).astype(jnp.int32), detected
-
-
-def symmetric_chunk_compute(params, state, hist, scal, *, chunk: int):
-    """Advance every cell ``chunk`` cycles and re-evaluate report + drift
-    + convergence — the whole per-chunk body of the adaptive symmetric
-    core, one launch worth of work.  All operands/results row-stacked."""
-    p = SymmetricFlitParams(*[params[i] for i in range(11)])
-    x, y, backlog = params[11], params[12], params[13]
-    step = _symmetric_stepfn(p, x, y, backlog)
-    core = tuple(state[i] for i in range(7))
-    D, TD, t = state[7], state[8], state[9]
-    rep_prev = state[10]
-
-    def body(_, carry):
-        core, D, TD, t = carry
-        core, nd = step(core)
-        t = t + 1.0
-        return core, D + nd, TD + t * nd, t
-
-    core, D, TD, t = jax.lax.fori_loop(
-        0, chunk, body, (core, D, TD, t))
-
-    kf, mf, midf = scal[0, 0], scal[0, 1], scal[0, 2]
-    K0f, Kf, ch = scal[0, 3], scal[0, 4], scal[0, 5]
-    tol, exit_ok = scal[0, 6], scal[0, 7]
-    at_hor, drift_tol = scal[0, 8], scal[0, 9]
-
-    # report: triangular trailing-window mean blended with the observed
-    # warm prefix — float transcription of flitsim's report()/
-    # _tri_window_mean (chunk indices are small ints, exact in f32)
-    denom = 2.0 * params[8] / 128.0
-    D_m = jnp.where(mf == kf, D, hist[5])
-    TD_m = jnp.where(mf == kf, TD, hist[6])
-    D_mid = jnp.where(midf == kf, D, hist[7])
-    TD_mid = jnp.where(midf == kf, TD, hist[8])
-    b_i, b_m, b_j = mf * ch, midf * ch, kf * ch
-    c1, c2 = b_m - b_i, b_j - b_m
-    w_sum = c1 * (c1 + 1.0) / 2.0 + c2 * (c2 - 1.0) / 2.0
-    num = ((TD_mid - TD_m) - b_i * (D_mid - D_m)
-           + b_j * (D - D_mid) - (TD - TD_mid))
-    mu = num / (jnp.maximum(w_sum, 1.0) * denom)
-    wA = jnp.maximum(kf - K0f, 1.0) * ch
-    A = (D - hist[9]) / (wA * denom)
-    rep = jnp.where(kf > K0f,
-                    (A * (kf - K0f) + mu * (Kf - kf)) / (Kf - K0f), mu)
-
-    pools = jnp.stack(core[:5])
-    drift = jnp.max(jnp.abs(pools - hist[0:5]), axis=0) / DRIFT_SPAN
-    delta = jnp.abs(rep - rep_prev) / jnp.maximum(jnp.abs(rep), 1e-9)
-    conv = (((delta <= tol) & (drift < drift_tol) & (exit_ok > 0.0))
-            | (at_hor > 0.0)).astype(jnp.float32)
-
-    pad = jnp.zeros_like(D)
-    return jnp.stack(list(core) + [D, TD, t, rep, conv]
-                     + [pad] * (SYM_ROWS - 12))
 
 
 def asymmetric_periodic_compute(params, *, n_accesses: int):
@@ -342,65 +250,3 @@ def symmetric_periodic_compute(params, *, n_flits: int):
     return jnp.stack([rep, detected.astype(jnp.float32),
                       jnp.where(detected, d, 0).astype(jnp.float32)]
                      + [pad] * (SYM_PERIODIC_ROWS - 3))
-
-
-def pipelining_chunk_compute(params, state, hist, scal, *, chunk: int):
-    """Per-chunk body of the adaptive Fig-13 pipelining core, row-stacked.
-
-    The per-cell device rotation (``dev = idx % k``; read/update row
-    ``dev`` of the ready table) is expressed as a one-hot mask over the
-    padded PIPE_MAX_K ready rows so the whole tile advances with dense
-    vector ops — no per-cell dynamic indexing."""
-    kdev, ucie, dev_ui = params[0], params[1], params[2]
-    dev_ready = state[0:PIPE_MAX_K]
-    link_free, idx = state[PIPE_MAX_K], state[PIPE_MAX_K + 1]
-    rep_prev = state[PIPE_MAX_K + 2]
-    rows = jax.lax.broadcasted_iota(
-        jnp.int32, (PIPE_MAX_K, dev_ready.shape[1]), 0).astype(jnp.float32)
-
-    def body(_, carry):
-        dev_ready, link_free, idx = carry
-        # idx and k are small exact f32 ints, so the float modulo is exact
-        dev = idx - jnp.floor(idx / kdev) * kdev
-        sel = rows == dev[None, :]
-        ready = jnp.sum(jnp.where(sel, dev_ready, 0.0), axis=0)
-        start = jnp.maximum(ready, link_free)
-        dev_ready = jnp.where(sel, start + dev_ui, dev_ready)
-        return dev_ready, start + ucie, idx + 1.0
-
-    dev_ready, link_free, idx = jax.lax.fori_loop(
-        0, chunk, body, (dev_ready, link_free, idx))
-
-    kf, Kf, ch = scal[0, 0], scal[0, 1], scal[0, 2]
-    tol, exit_ok, at_hor = scal[0, 3], scal[0, 4], scal[0, 5]
-    n_lines = scal[0, 6]
-    T1 = jnp.where(kf == 1.0, link_free, hist[0])
-    ahat = (link_free - T1) / jnp.maximum((kf - 1.0) * ch, 1.0)
-    rep = n_lines * ucie / jnp.maximum(
-        link_free + ahat * (Kf - kf) * ch, 1e-9)
-    delta = jnp.abs(rep - rep_prev) / jnp.maximum(jnp.abs(rep), 1e-9)
-    conv = (((delta <= tol) & (exit_ok > 0.0))
-            | (at_hor > 0.0)).astype(jnp.float32)
-
-    pad = jnp.zeros_like(link_free)
-    return jnp.stack(list(dev_ready) + [link_free, idx, rep, conv]
-                     + [pad] * (PIPE_ROWS - PIPE_MAX_K - 4))
-
-
-# -- jnp oracles (what the Pallas kernels are tested against) -----------------
-
-
-def symmetric_chunk_ref(params, state, hist, scal, *, chunk: int):
-    return symmetric_chunk_compute(params, state, hist, scal, chunk=chunk)
-
-
-def asymmetric_periodic_ref(params, *, n_accesses: int):
-    return asymmetric_periodic_compute(params, n_accesses=n_accesses)
-
-
-def symmetric_periodic_ref(params, *, n_flits: int):
-    return symmetric_periodic_compute(params, n_flits=n_flits)
-
-
-def pipelining_chunk_ref(params, state, hist, scal, *, chunk: int):
-    return pipelining_chunk_compute(params, state, hist, scal, chunk=chunk)

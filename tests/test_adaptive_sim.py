@@ -15,6 +15,9 @@ Contracts:
   * the ``write_buffer_lines`` bugfix field: default preserves numerics
     bit-for-bit, and the write path is now independently perturbable.
 """
+import dataclasses
+import re
+
 import numpy as np
 import pytest
 
@@ -41,19 +44,16 @@ class TestSimConfig:
     def test_validation(self):
         with pytest.raises(ValueError, match="mode"):
             SimConfig(mode="turbo")
-        with pytest.raises(ValueError, match="chunk"):
-            SimConfig(chunk=1)
-        with pytest.raises(ValueError, match="tol"):
-            SimConfig(tol=0.0)
-        with pytest.raises(ValueError, match="unroll"):
-            SimConfig(unroll=0)
         with pytest.raises(ValueError, match="max_cycles"):
             SimConfig(max_cycles=0)
+        # the loop constants are the engine's, not options
+        assert [f.name for f in dataclasses.fields(SimConfig)] == \
+            ["mode", "max_cycles", "trace_cycles"]
 
     def test_cache_keys_distinguish_configs(self):
         assert FIXED_SIM.key() == ("fixed",)
         assert ADAPTIVE_SIM.key() != FIXED_SIM.key()
-        assert SimConfig(mode="adaptive", tol=1e-4).key() != \
+        assert SimConfig(mode="adaptive", max_cycles=512).key() != \
             ADAPTIVE_SIM.key()
 
     def test_horizon_override(self):
@@ -95,14 +95,11 @@ class TestSimConfig:
         # the usable-chunk floor, so the runner must hand the run to the
         # fixed engine verbatim (bit-identity, not merely within tol)
         assert flitsim._divisor_chunk(62, 128) < 8
-        for engine in ("xla", "pallas"):
-            cfg = SimConfig(mode="adaptive", max_cycles=62, engine=engine)
-            a = sweep(protocols=["cxl_opt"], mixes=[(2, 1), (0, 1)],
-                      sim=cfg)
-            f = sweep(protocols=["cxl_opt"], mixes=[(2, 1), (0, 1)],
-                      n_flits=62)
-            np.testing.assert_array_equal(np.asarray(a.efficiency),
-                                          np.asarray(f.efficiency))
+        cfg = SimConfig(mode="adaptive", max_cycles=62)
+        a = sweep(protocols=["cxl_opt"], mixes=[(2, 1), (0, 1)], sim=cfg)
+        f = sweep(protocols=["cxl_opt"], mixes=[(2, 1), (0, 1)], n_flits=62)
+        np.testing.assert_array_equal(np.asarray(a.efficiency),
+                                      np.asarray(f.efficiency))
 
     def test_chunk_count_not_divisible_by_4_still_lands(self):
         # 162 = 2 * 81 carries a single factor of 2, so NO divisor can
@@ -398,3 +395,113 @@ class TestWriteBufferLines:
             mixes=[(0, 1)], backlogs=[64.0])
         eff = res["sim_efficiency"].values
         assert eff[1, 0, 0, 0] == pytest.approx(eff[0, 0, 0, 0], abs=1e-6)
+
+
+# -- the adaptive runner's paths: probe limits, gates, programs, launches ----
+
+#: cells of the probe-limit grids; the probe keeps its answer while at
+#: most max(cells // 4, 8) cells go uncertified (10 of 40)
+LIMIT_CELLS = 40
+PROBE_LIMIT = max(LIMIT_CELLS // 4, 8)
+
+
+def _probe_limit_grid(family: str, undetected: int) -> dict:
+    """One protocol x 40 mixes: ``40 - undetected`` mixes whose reduced
+    read fraction has a denominator <= PERIOD_MAX (the probe certifies
+    them) and ``undetected`` of denominator 127 (it never can)."""
+    aperiodic = [(a, 127 - a) for a in range(1, undetected + 1)]
+    if family == "symmetric":
+        periodic = [(i, 32 - i) for i in range(LIMIT_CELLS - undetected)]
+        return dict(protocols=("chi",), backlogs=[1.0],
+                    mixes=periodic + aperiodic, n_flits=512)
+    periodic = [(i, 40 - i) for i in range(LIMIT_CELLS - undetected)]
+    return dict(protocols=("lpddr6_asym",), mixes=periodic + aperiodic,
+                n_accesses=1024)
+
+
+def _module_names(family: str) -> set:
+    return {re.search(r"HloModule (\w+)", exe.as_text()).group(1)
+            for exe in space_mod.programs([family]).values()}
+
+
+def _trace(protocol: str):
+    return flitsim.simulate_trace_grid(
+        (protocol,), np.asarray([[50.0, 80.0]]), np.asarray([[50.0, 20.0]]),
+        np.asarray([[2.0, 8.0]]), sim=SimConfig(trace_cycles=64))
+
+
+#: (family, path) -> a small run that compiles that path's program
+PATH_RUNS = {
+    ("symmetric", "fixed"): lambda: sweep(
+        protocols=("chi",), mixes=[(2, 1)], n_flits=256),
+    ("symmetric", "core"): lambda: sweep(
+        protocols=("chi",), mixes=[(2, 1)], backlogs=[64.0], n_flits=256,
+        sim=ADAPTIVE_SIM),
+    ("symmetric", "cells"): lambda: sweep(
+        sim=ADAPTIVE_SIM, **_probe_limit_grid("symmetric", PROBE_LIMIT)),
+    ("symmetric", "trace"): lambda: _trace("chi"),
+    ("asymmetric", "fixed"): lambda: sweep(
+        protocols=("lpddr6_asym",), mixes=[(2, 1)], n_accesses=256),
+    ("asymmetric", "probe"): lambda: sweep(
+        protocols=("lpddr6_asym",), mixes=[(2, 1)], n_accesses=1024,
+        sim=ADAPTIVE_SIM),
+    ("asymmetric", "trace"): lambda: _trace("lpddr6_asym"),
+    ("pipelining", "fixed"): lambda: sweep_pipelining((1, 2), n_lines=64),
+    ("pipelining", "core"): lambda: sweep_pipelining(
+        (1, 2), n_lines=64, sim=ADAPTIVE_SIM),
+}
+
+
+class TestAdaptivePaths:
+    @pytest.mark.parametrize("undetected", [PROBE_LIMIT, PROBE_LIMIT + 1])
+    @pytest.mark.parametrize("family", ["symmetric", "asymmetric"])
+    def test_probe_fallback_limit(self, family, undetected):
+        kw = _probe_limit_grid(family, undetected)
+        f = np.asarray(sweep(**kw).efficiency)
+        a = np.asarray(sweep(sim=ADAPTIVE_SIM, **kw).efficiency)
+        info = flitsim.last_run_info()[f"flitsim.{family}"]
+        if undetected <= PROBE_LIMIT:
+            # the probe keeps its answer; its misses escalate exactly
+            assert sum(info["periods"].values()) == \
+                LIMIT_CELLS - undetected
+            assert info["stragglers"] == undetected
+            np.testing.assert_allclose(a, f, atol=1e-6)
+        else:
+            # one miss too many: the chunked core runs the whole grid
+            assert "periods" not in info
+            assert float(np.max(np.abs(a - f))) <= 1e-3
+
+    @pytest.mark.parametrize("above", [False, True])
+    def test_symmetric_probe_backlog_gate(self, above):
+        from repro.kernels.flit_sim.ref import SYM_PERIODIC_MAX_BACKLOG
+        top = np.float32(SYM_PERIODIC_MAX_BACKLOG)
+        if above:
+            top = np.nextafter(top, np.float32(np.inf))
+        flitsim.clear_compile_cache()
+        sweep(protocols=("chi",), mixes=[(1, 1), (3, 1)],
+              backlogs=[1.0, float(top)], n_flits=512, sim=ADAPTIVE_SIM)
+        probes = [k for k in space_mod.programs(["flitsim.symmetric"])
+                  if "periodic" in k]
+        assert bool(probes) is not above
+
+    @pytest.mark.parametrize("family, path", sorted(PATH_RUNS))
+    def test_program_module_name(self, family, path):
+        flitsim.clear_compile_cache()
+        PATH_RUNS[family, path]()
+        assert f"jit_flitsim_{family}_{path}" in _module_names(
+            f"flitsim.{family}")
+
+    @pytest.mark.parametrize("run, launches", [
+        ("probe", 1), ("probe_escalated", 2), ("core", 1)])
+    def test_launches(self, run, launches):
+        if run == "core":       # saturated: the probe is skipped
+            PATH_RUNS["symmetric", "core"]()
+            info = flitsim.last_run_info()["flitsim.symmetric"]
+        else:
+            undetected = 1 if run == "probe_escalated" else 0
+            sweep(sim=ADAPTIVE_SIM,
+                  **_probe_limit_grid("asymmetric", undetected))
+            info = flitsim.last_run_info()["flitsim.asymmetric"]
+            assert info["stragglers"] == undetected
+        assert info["launches"] == launches
+        assert "engine" not in info
