@@ -32,6 +32,16 @@ engine body:
     res.efficiency                              # [P, B, M] (or [P, M])
     flitsim.sweep_perturbed([{}, {"credit_lines": 0.5}])   # sensitivity
 
+The lowering onto those programs runs on the host: :func:`simulate_grid`
+and :func:`simulate_trace_grid` build the mixes, backlogs and parameter
+stacks (``perturbed_stack``) as numpy ``float32`` arrays and hand them
+straight to the cached executables, so a compiled call transfers all its
+arguments at once and no standalone put or eager op runs around it.  Each
+family's answer is read back once and split, broadcast and stacked in
+numpy: both functions return numpy ``float32`` arrays.
+``last_run_info()["space"]`` counts the engine calls, the host arrays
+handed to them and the answers' readbacks of one ``DesignSpace.evaluate``.
+
 ``_sweep_pipelining_impl`` batches the Fig-13 model over device counts — and,
 when ``ucie_line_ui`` / ``device_line_ui`` are sequences, over the full
 ``[k x ucie_line_ui x device_line_ui]`` joint grid (faster DRAM generations
@@ -82,6 +92,7 @@ that ``bench_flitsim`` reports.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -105,6 +116,12 @@ from repro.core.protocols.lpddr6_ucie import LPDDR6OnUCIe
 
 def _f32(v) -> jnp.ndarray:
     return jnp.asarray(v, dtype=jnp.float32)
+
+
+def _host_f32(v) -> np.ndarray:
+    """:func:`_f32` on the host: the grids' lowering keeps its arrays in
+    numpy until a compiled engine call takes them."""
+    return np.asarray(v, dtype=np.float32)
 
 
 def _check_mix(x: float, y: float) -> None:
@@ -157,18 +174,20 @@ class _Stackable:
     def perturbed_stack(cls, bases: Sequence["_Stackable"],
                         perts: Sequence[Mapping[str, float]]):
         """``cls.stack([b.perturbed(p) for p in perts for b in bases])``
-        (row ``q * len(bases) + k``), built a field at a time: each value
-        is the same float64 product of base and scale as
+        (row ``q * len(bases) + k``), built a field at a time on the
+        host: each value is the same float64 product of base and scale as
         :func:`apply_perturbation` forms, rounded once to f32, with no
-        parameter object per row."""
+        parameter object per row.  The leaves are numpy ``float32``
+        ``[len(perts) * len(bases)]`` arrays; they reach the device with
+        the compiled call (or the placement) that takes them."""
         cols = []
         for f in dataclasses.fields(cls):
             base = np.asarray([float(getattr(b, f.name)) for b in bases],
                               np.float64)
             scale = np.asarray([float(p.get(f.name, 1.0)) for p in perts],
                                np.float64)
-            cols.append(_f32((scale[:, None] * base[None, :]).reshape(-1)
-                             .astype(np.float32)))
+            cols.append((scale[:, None] * base[None, :]).reshape(-1)
+                        .astype(np.float32))
         return cls(*cols)
 
     def perturbed(self, pert: Mapping[str, float]) -> "_Stackable":
@@ -1063,6 +1082,47 @@ def record_counters(key: str, **values: Any) -> None:
     _LAST_RUN_INFO[key] = {"mode": "counters", **values}
 
 
+#: the host path of the grids evaluated inside :func:`_count_space`
+_SPACE_COUNTS: Dict[str, int] = dict.fromkeys(
+    ("engine_calls", "host_args", "host_arg_bytes", "readbacks"), 0)
+
+
+def _call_engine(fn, *args):
+    """Run the compiled engine program ``fn`` on ``args``.  Its host
+    (numpy) leaves travel with the call, one transfer for all of them;
+    the call and those leaves are counted for ``last_run_info()["space"]``."""
+    host = [a for a in jax.tree_util.tree_leaves(args)
+            if isinstance(a, np.ndarray)]
+    _SPACE_COUNTS["engine_calls"] += 1
+    _SPACE_COUNTS["host_args"] += len(host)
+    _SPACE_COUNTS["host_arg_bytes"] += sum(a.nbytes for a in host)
+    return fn(*args)
+
+
+def _read_back(a) -> np.ndarray:
+    """A family's answer as a host array: a device array is read back
+    once (counted for ``last_run_info()["space"]``), a host array passes
+    through."""
+    if isinstance(a, jax.Array):
+        _SPACE_COUNTS["readbacks"] += 1
+    return np.asarray(a)
+
+
+@contextlib.contextmanager
+def _count_space():
+    """Count the host path of the grids evaluated inside (one
+    materialized ``DesignSpace.evaluate``) into
+    ``last_run_info()["space"]``: ``engine_calls`` (compiled engine
+    programs run), ``host_args`` / ``host_arg_bytes`` (the numpy arrays
+    handed to them, and their bytes) and ``readbacks`` (device-to-host
+    reads of the families' answers: one per family, made by the probe
+    whose whole output, flags and answer, is read back at once, by the
+    escalation that scatters its stragglers, or by the assembly)."""
+    _SPACE_COUNTS.update(dict.fromkeys(_SPACE_COUNTS, 0))
+    yield
+    record_counters("space", **_SPACE_COUNTS)
+
+
 def run_mark() -> Dict[str, Any]:
     """The records :func:`last_run_info` holds now, for
     :func:`runs_since`."""
@@ -1104,12 +1164,12 @@ def _escalate_stragglers(family: str, cells_grid_fn, horizon: int, rep,
         args = args_builder(idx)
         cells_fn = cached_program(family, ("cells", idx.shape[0], horizon),
                                   cells_grid_fn, args, path="cells")
-        exact = cells_fn(*args)
+        exact = _call_engine(cells_fn, *args)
         with TraceAnnotation("repro.engine.readback", family=family):
             exact = np.asarray(exact)
-            rep_np = np.asarray(rep).copy()
+            rep_np = _read_back(rep).copy()
         rep_np[~conv_np] = exact[:int((~conv_np).sum())]
-        return jnp.asarray(rep_np)
+        return rep_np
 
 
 def _escalation_budget(cells: int, chunk: int, horizon: int) -> int:
@@ -1209,13 +1269,15 @@ def _run_asymmetric_periodic(pstack, x, y, horizon: int, sim: SimConfig):
     fn = cached_program("flitsim.asymmetric",
                         (P, M, horizon, "periodic") + sim.key(),
                         build, (pstack, x, y), path="probe")
-    out = fn(pstack, x, y)
+    out = _call_engine(fn, pstack, x, y)
     with TraceAnnotation("repro.engine.readback",
                          family="flitsim.asymmetric"):
-        det_np = np.asarray(out[1, :cells]) > 0.5
+        out = np.asarray(out)
+    det_np = out[1, :cells] > 0.5
     undet = int((~det_np).sum())
     if undet > max(cells // 4, 8):
         return None
+    _SPACE_COUNTS["readbacks"] += 1     # the answer came with the flags
     rep = out[0, :cells].reshape(P, M)
     launches = 1
     if undet:
@@ -1259,13 +1321,15 @@ def _run_symmetric_periodic(pstack, x, y, backlogs, horizon: int,
     fn = cached_program("flitsim.symmetric",
                         (P, B, M, horizon, "periodic") + sim.key(),
                         build, (pstack, x, y, backlogs), path="probe")
-    out = fn(pstack, x, y, backlogs)
+    out = _call_engine(fn, pstack, x, y, backlogs)
     with TraceAnnotation("repro.engine.readback",
                          family="flitsim.symmetric"):
-        det_np = np.asarray(out[1, :cells]) > 0.5
+        out = np.asarray(out)
+    det_np = out[1, :cells] > 0.5
     undet = int((~det_np).sum())
     if undet > max(cells // 4, 8):
         return None
+    _SPACE_COUNTS["readbacks"] += 1     # the answer came with the flags
     rep = out[0, :cells].reshape(P, B, M)
     launches = 1
     if undet:
@@ -1297,7 +1361,7 @@ def _run_symmetric(pstack, x, y, backlogs, n_flits: int,
             "flitsim.symmetric", (P, B, M, n_flits) + sim.key(),
             functools.partial(_symmetric_grid, n_flits=n_flits),
             (pstack, x, y, backlogs), path="fixed")
-        return fn(pstack, x, y, backlogs)
+        return _call_engine(fn, pstack, x, y, backlogs)
     horizon = sim.horizon(n_flits)
     chunk = _divisor_chunk(horizon, _ADAPTIVE_CHUNK)
     if chunk < 8:               # divisor-poor horizon: adaptive degrades
@@ -1338,7 +1402,7 @@ def _run_symmetric_core(pstack, x, y, backlogs, horizon: int, chunk: int,
                           chunk=chunk, unroll=_ADAPTIVE_UNROLL,
                           tol=_ADAPTIVE_TOL, budget=budget),
         (pstack, x, y, backlogs), path="core")
-    rep, conv, k_exit, conv_at = fn(pstack, x, y, backlogs)
+    rep, conv, k_exit, conv_at = _call_engine(fn, pstack, x, y, backlogs)
     stragglers = 0
     if budget > 0:                      # budget 0 can only exit converged
         with TraceAnnotation("repro.engine.readback",
@@ -1371,7 +1435,7 @@ def _run_asymmetric(pstack, x, y, n_accesses: int,
             "flitsim.asymmetric", (P, M, n_accesses) + sim.key(),
             functools.partial(_asymmetric_grid, n_accesses=n_accesses),
             (pstack, x, y), path="fixed")
-        return fn(pstack, x, y)
+        return _call_engine(fn, pstack, x, y)
     horizon = sim.horizon(n_accesses)
     chunk = _divisor_chunk(horizon, _ADAPTIVE_CHUNK)
     if chunk < 8:
@@ -1403,7 +1467,7 @@ def _run_asymmetric_core(pstack, x, y, horizon: int, chunk: int,
                           chunk=chunk, unroll=_ADAPTIVE_UNROLL,
                           tol=_ADAPTIVE_TOL, budget=budget),
         (pstack, x, y), path="core")
-    rep, conv, k_exit, conv_at = fn(pstack, x, y)
+    rep, conv, k_exit, conv_at = _call_engine(fn, pstack, x, y)
     stragglers = 0
     if budget > 0:
         with TraceAnnotation("repro.engine.readback",
@@ -1437,7 +1501,7 @@ def _run_pipelining(ks, ucie_line_uis, device_line_uis, max_k: int,
             functools.partial(_pipelining_grid, max_k=max_k,
                               n_lines=n_lines),
             (ks, ucie_line_uis, device_line_uis), path="fixed")
-        return fn(ks, ucie_line_uis, device_line_uis)
+        return _call_engine(fn, ks, ucie_line_uis, device_line_uis)
     horizon = sim.horizon(n_lines)
     chunk = _divisor_chunk(horizon, _ADAPTIVE_CHUNK)
     if chunk < 8:
@@ -1451,7 +1515,8 @@ def _run_pipelining(ks, ucie_line_uis, device_line_uis, max_k: int,
                               n_lines=horizon, chunk=chunk,
                               unroll=_ADAPTIVE_UNROLL, tol=_ADAPTIVE_TOL),
             (ks, ucie_line_uis, device_line_uis), path="core")
-        rep, conv, k_exit, conv_at = fn(ks, ucie_line_uis, device_line_uis)
+        rep, conv, k_exit, conv_at = _call_engine(
+            fn, ks, ucie_line_uis, device_line_uis)
         jax.block_until_ready(rep)
     _record_adaptive("flitsim.pipelining", horizon, chunk, k_exit, conv_at,
                      0,                 # exits only converged / at horizon
@@ -1472,7 +1537,7 @@ def _run_symmetric_trace(pstack, xs, ys, bls, cycles: int,
         functools.partial(_symmetric_trace_grid, n_phases=N,
                           cycles=cycles),
         (pstack, xs, ys, bls), path="trace")
-    rep = fn(pstack, xs, ys, bls)
+    rep = _call_engine(fn, pstack, xs, ys, bls)
     _record_trace("flitsim.symmetric", N, cycles, P * T)
     return rep
 
@@ -1486,7 +1551,7 @@ def _run_asymmetric_trace(pstack, xs, ys, cycles: int, sim: SimConfig):
         functools.partial(_asymmetric_trace_grid, n_phases=N,
                           cycles=cycles),
         (pstack, xs, ys), path="trace")
-    rep = fn(pstack, xs, ys)
+    rep = _call_engine(fn, pstack, xs, ys)
     _record_trace("flitsim.asymmetric", N, cycles, P * T)
     return rep
 
@@ -1499,7 +1564,7 @@ def simulate_grid(protocols: Sequence[str], x, y, backlogs, *,
                   = None,
                   n_flits: int = 2048,
                   n_accesses: int = 4096,
-                  sim: Optional[SimConfig] = None) -> jnp.ndarray:
+                  sim: Optional[SimConfig] = None) -> np.ndarray:
     """Evaluate the full ``[Q perturbations, P protocols, B backlogs,
     M mixes]`` grid, one compiled call per simulator family.
 
@@ -1508,7 +1573,15 @@ def simulate_grid(protocols: Sequence[str], x, y, backlogs, *,
     ``perturbations`` are multiplicative ``{field: scale}`` overrides
     folded into the parameter stacks (the protocol axis becomes ``Q*P``
     rows of one pytree), so sensitivity sweeps ride the exact same
-    executables as the baseline.  Returns efficiency ``[Q, P, B, M]``.
+    executables as the baseline.  Returns efficiency ``[Q, P, B, M]`` as
+    a numpy ``float32`` array.
+
+    The lowering and the assembly run on the host: the mixes, backlogs
+    and parameter stacks stay numpy ``float32`` until the compiled engine
+    call takes them (all its arguments in one transfer), and each
+    family's answer is read back once and split, broadcast and stacked in
+    numpy (``last_run_info()["space"]`` counts the calls, the host
+    arrays and the readbacks of a ``DesignSpace.evaluate``).
 
     ``sim`` selects the execution config: :data:`FIXED_SIM` (default,
     bit-identical full-horizon scan) or :data:`ADAPTIVE_SIM` (chunked
@@ -1542,9 +1615,9 @@ def simulate_grid(protocols: Sequence[str], x, y, backlogs, *,
                     f"perturbation {p} applies to no parameter of the "
                     f"selected protocols {keys}; applicable fields: "
                     f"{sorted(active_fields)}")
-        x = _f32(np.asarray(x).reshape(-1))
-        y = _f32(np.asarray(y).reshape(-1))
-        b = _f32(np.asarray(backlogs).reshape(-1))
+        x = _host_f32(x).reshape(-1)
+        y = _host_f32(y).reshape(-1)
+        b = _host_f32(backlogs).reshape(-1)
         n_q, n_b, n_m = len(perts), b.shape[0], x.shape[0]
         sym_stack = (SymmetricFlitParams.perturbed_stack(
             [SYMMETRIC_PARAMS[k] for k in sym_keys], perts)
@@ -1557,26 +1630,28 @@ def simulate_grid(protocols: Sequence[str], x, y, backlogs, *,
     asym_grid = (_run_asymmetric(asym_stack, x, y, int(n_accesses), sim=sim)
                  if asym_keys else None)
     with TraceAnnotation("repro.space.assemble"):
-        per_key: Dict[str, jnp.ndarray] = {}        # key -> [Q, B, M]
+        per_key: Dict[str, np.ndarray] = {}         # key -> [Q, B, M]
         if sym_keys:
-            grid = sym_grid.reshape((n_q, len(sym_keys), n_b, n_m))
+            grid = _read_back(sym_grid).reshape(
+                (n_q, len(sym_keys), n_b, n_m))
             for i, k in enumerate(sym_keys):
                 per_key[k] = grid[:, i]
         if asym_keys:
-            grid = asym_grid.reshape((n_q, len(asym_keys), n_m))
+            grid = _read_back(asym_grid).reshape((n_q, len(asym_keys), n_m))
             for i, k in enumerate(asym_keys):
-                per_key[k] = jnp.broadcast_to(grid[:, i, None, :],
-                                              (n_q, n_b, n_m))
-        return jnp.stack([per_key[k] for k in keys], axis=1)  # [Q, P, B, M]
+                per_key[k] = np.broadcast_to(grid[:, i, None, :],
+                                             (n_q, n_b, n_m))
+        return np.stack([per_key[k] for k in keys], axis=1)  # [Q, P, B, M]
 
 
 def simulate_trace_grid(protocols: Sequence[str], xs, ys, backlogs, *,
                         perturbations: Optional[
                             Sequence[Mapping[str, float]]] = None,
                         n_flits: int = 2048, n_accesses: int = 4096,
-                        sim: Optional[SimConfig] = None) -> jnp.ndarray:
+                        sim: Optional[SimConfig] = None) -> np.ndarray:
     """Evaluate ``T`` traffic traces of ``N`` phases each through the
-    trace-scan cores: per-PHASE efficiency ``[Q, P, T, N]``.
+    trace-scan cores: per-PHASE efficiency ``[Q, P, T, N]``, a numpy
+    ``float32`` array.
 
     ``xs`` / ``ys`` / ``backlogs`` are ``[T, N]`` phase grids (read /
     write mix percentages and queue backlog per phase).  Queue and credit
@@ -1590,6 +1665,10 @@ def simulate_trace_grid(protocols: Sequence[str], xs, ys, backlogs, *,
     static horizon — ``n_flits`` symmetric, ``n_accesses`` asymmetric).
     Phase DURATIONS are not consumed here: the design space applies them
     as aggregation weights over the returned per-phase grid.
+
+    As in :func:`simulate_grid`, the phase grids and parameter stacks stay
+    numpy until the trace program's call takes them, and each family's
+    answer is read back once and stacked on the host.
     """
     sim = sim if sim is not None else FIXED_SIM
     keys = tuple(protocols)
@@ -1614,22 +1693,23 @@ def simulate_trace_grid(protocols: Sequence[str], xs, ys, backlogs, *,
                 f"perturbation {p} applies to no parameter of the selected "
                 f"protocols {keys}; applicable fields: "
                 f"{sorted(active_fields)}")
-    xs = _f32(np.asarray(xs))
-    ys = _f32(np.asarray(ys))
-    bls = _f32(np.asarray(backlogs))
+    xs = _host_f32(xs)
+    ys = _host_f32(ys)
+    bls = _host_f32(backlogs)
     if xs.ndim != 2 or xs.shape != ys.shape or xs.shape != bls.shape:
         raise ValueError(
             f"trace phase grids must share one [T, N] shape; got "
             f"xs {xs.shape}, ys {ys.shape}, backlogs {bls.shape}")
     n_q, (n_t, n_p) = len(perts), xs.shape
 
-    per_key: Dict[str, jnp.ndarray] = {}            # key -> [Q, T, N]
+    per_key: Dict[str, np.ndarray] = {}             # key -> [Q, T, N]
     sym_keys = [k for k in keys if k in SYMMETRIC_PARAMS]
     if sym_keys:
         cycles = int(sim.trace_cycles or n_flits)
         pstack = SymmetricFlitParams.perturbed_stack(
             [SYMMETRIC_PARAMS[k] for k in sym_keys], perts)
-        grid = _run_symmetric_trace(pstack, xs, ys, bls, cycles, sim)
+        grid = _read_back(_run_symmetric_trace(pstack, xs, ys, bls, cycles,
+                                               sim))
         grid = grid.reshape((n_q, len(sym_keys), n_t, n_p))
         for i, k in enumerate(sym_keys):
             per_key[k] = grid[:, i]
@@ -1638,11 +1718,11 @@ def simulate_trace_grid(protocols: Sequence[str], xs, ys, backlogs, *,
         cycles = int(sim.trace_cycles or n_accesses)
         pstack = AsymmetricLaneParams.perturbed_stack(
             [ASYMMETRIC_PARAMS[k] for k in asym_keys], perts)
-        grid = _run_asymmetric_trace(pstack, xs, ys, cycles, sim)
+        grid = _read_back(_run_asymmetric_trace(pstack, xs, ys, cycles, sim))
         grid = grid.reshape((n_q, len(asym_keys), n_t, n_p))
         for i, k in enumerate(asym_keys):
             per_key[k] = grid[:, i]
-    return jnp.stack([per_key[k] for k in keys], axis=1)   # [Q, P, T, N]
+    return np.stack([per_key[k] for k in keys], axis=1)    # [Q, P, T, N]
 
 
 # -- scalar entry points (thin wrappers over a [1, 1, 1] grid) ----------------
@@ -1713,9 +1793,9 @@ class SweepResult:
     protocols: Tuple[str, ...]
     mixes: Tuple[Tuple[float, float], ...]
     backlogs: Optional[Tuple[float, ...]]
-    efficiency: jnp.ndarray
+    efficiency: np.ndarray
 
-    def for_protocol(self, key: str) -> jnp.ndarray:
+    def for_protocol(self, key: str) -> np.ndarray:
         return self.efficiency[self.protocols.index(key)]
 
 
@@ -1754,8 +1834,8 @@ def _sweep_impl(protocols: Optional[Sequence[str]] = None,
         backlog_vals = tuple(
             float(b) for b in np.atleast_1d(np.asarray(backlogs)))
 
-    x = _f32([m[0] for m in mix_tuples])
-    y = _f32([m[1] for m in mix_tuples])
+    x = _host_f32([m[0] for m in mix_tuples])
+    y = _host_f32([m[1] for m in mix_tuples])
     eff = simulate_grid(keys, x, y, backlog_vals, n_flits=n_flits,
                         n_accesses=n_accesses, sim=sim)[0]  # [P, B, M]
     if squeeze_b:

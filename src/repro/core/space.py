@@ -1196,6 +1196,7 @@ class DesignSpace:
                 return streaming.stream_evaluate(
                     self, metrics, sim if sim is not None else self.sim,
                     stream)
+            from repro.core import flitsim
             cfg = sim if sim is not None else self.sim
             wanted = tuple(metrics) if metrics is not None else \
                 self._default_metrics()
@@ -1207,16 +1208,19 @@ class DesignSpace:
                 raise ValueError(f"unknown metrics {unknown}; choose from "
                                  f"{known}")
             arrays: Dict[str, SpaceArray] = {}
-            if any(m in wanted for m in ANALYTIC_METRICS + SYSTEM_METRICS):
-                arrays.update(self._eval_catalog(wanted))
-            if any(m in wanted for m in APPROACH_METRICS):
-                arrays.update(self._eval_approaches(wanted))
-            if any(m in wanted for m in SIM_METRICS + SIM_PHY_METRICS):
-                arrays.update(self._eval_sim(wanted, cfg))
-            if any(m in wanted for m in TRACE_METRICS + TRACE_PHY_METRICS):
-                arrays.update(self._eval_trace(wanted, cfg))
-            if any(m in wanted for m in PIPELINE_METRICS):
-                arrays.update(self._eval_pipelining(wanted, cfg))
+            with flitsim._count_space():
+                if any(m in wanted
+                       for m in ANALYTIC_METRICS + SYSTEM_METRICS):
+                    arrays.update(self._eval_catalog(wanted))
+                if any(m in wanted for m in APPROACH_METRICS):
+                    arrays.update(self._eval_approaches(wanted))
+                if any(m in wanted for m in SIM_METRICS + SIM_PHY_METRICS):
+                    arrays.update(self._eval_sim(wanted, cfg))
+                if any(m in wanted
+                       for m in TRACE_METRICS + TRACE_PHY_METRICS):
+                    arrays.update(self._eval_trace(wanted, cfg))
+                if any(m in wanted for m in PIPELINE_METRICS):
+                    arrays.update(self._eval_pipelining(wanted, cfg))
             return SpaceResult(axes=self.axes, arrays=arrays, sim=cfg)
 
     def _perturbations(self) -> List[Dict[str, float]]:
@@ -1368,7 +1372,8 @@ class DesignSpace:
             n_flits=self.n_flits, n_accesses=self.n_accesses, sim=sim)
         with jax.profiler.TraceAnnotation("repro.space.assemble"):
             # eff: [Q, P, B, Mf] -> named dims, dropping absent axes
-            eff = np.asarray(eff).reshape(eff.shape[:3] + mix_shape)
+            eff = flitsim._read_back(eff)
+            eff = eff.reshape(eff.shape[:3] + mix_shape)
             dims: List[str] = ["protocol_param", "protocol", "backlog"]
             coords: List[Tuple] = [
                 pert_ax.labels if pert_ax is not None else ("baseline",),
@@ -1443,7 +1448,7 @@ class DesignSpace:
         pert_ax = self.axes.get("protocol_param")
         perts = ([dict(p) for _, p in pert_ax.values]
                  if pert_ax is not None else [{}])
-        eff = np.asarray(flitsim.simulate_trace_grid(
+        eff = flitsim._read_back(flitsim.simulate_trace_grid(
             keys, xs, ys, bls, perturbations=perts,
             n_flits=self.n_flits, n_accesses=self.n_accesses, sim=sim))
         # eff: per-phase [Q, P, T, N]; the duration-weighted aggregate is
@@ -1508,7 +1513,7 @@ class DesignSpace:
         d_ax = self.axes.get("device_line_ui")
         us = tuple(u_ax.values) if u_ax is not None else (16.0,)
         ds = tuple(d_ax.values) if d_ax is not None else (64.0,)
-        util = np.asarray(flitsim._sweep_pipelining_impl(
+        util = flitsim._read_back(flitsim._sweep_pipelining_impl(
             k_ax.values, n_lines=self.n_lines, ucie_line_ui=us,
             device_line_ui=ds, sim=sim))        # [K, U, D]
         dims: List[str] = ["k"]
